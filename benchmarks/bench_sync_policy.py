@@ -24,6 +24,7 @@ SCRIPT = textwrap.dedent("""
     from repro.configs import get_config
     from repro.configs.base import ShapeConfig
     from repro.distributed.sharding import mesh_context, named_shardings
+    from repro.launch.mesh import make_test_mesh
     from repro.models import model as MDL
     from repro.roofline.hlo_analyzer import analyze_hlo
     from repro.train.optimizer import AdamWConfig, opt_state_shapes
@@ -32,7 +33,7 @@ SCRIPT = textwrap.dedent("""
     cfg = get_config("granite-moe-1b-a400m", smoke=True)
     shape = ShapeConfig("t", 64, 8, "train", microbatches=4)
     ocfg = AdamWConfig()
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = make_test_mesh(data=4, model=2)
     pshapes = MDL.param_shapes(cfg)
     out = {}
     for policy in ("unopt", "lc", "afe", "afe_bucket"):

@@ -46,8 +46,9 @@ from . import (
 from .common import set_run_context
 
 ALL = {
-    "adoption": bench_adoption.run,
     "ep": bench_ep.run,
+    "sync": bench_sync_policy.run,
+    "adoption": bench_adoption.run,
     "faults": bench_faults.run,
     "grain": bench_grain.run,
     "slo": bench_slo.run,
@@ -60,9 +61,14 @@ ALL = {
     "batcher": bench_batcher.run,
     "tenants": bench_tenants.run,
     "sched": bench_sched.run,
-    "sync": bench_sync_policy.run,
     "roofline": bench_roofline.run,
 }
+
+#: benches that run in a Python child process (it sets a virtual-device
+#: XLA_FLAGS).  On a machine with a chip, the child cannot take the chip
+#: once this process has touched JAX, so these run only before any
+#: in-process bench.
+CHILD_PROCESS = ("ep", "sync")
 
 
 def _call(fn, seed, repeats):
@@ -91,6 +97,13 @@ def main(argv=None):
     unknown = [n for n in names if n not in ALL]
     if unknown:
         ap.error(f"unknown benchmarks: {unknown} (have: {' '.join(ALL)})")
+    n_first = next((i for i, n in enumerate(names) if n not in CHILD_PROCESS),
+                   len(names))
+    late = [n for n in names[n_first:] if n in CHILD_PROCESS]
+    if late:
+        ap.error(f"{late} run in a child process, which cannot use the chip "
+                 "after an earlier bench in this process has touched JAX: "
+                 "list them first or run them alone")
     set_run_context(seed=args.seed, repeats=args.repeats)
     t0 = time.perf_counter()
     for name in names:

@@ -19,11 +19,6 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-try:  # jax >= 0.6 re-exports shard_map at the top level
-    from jax import shard_map  # type: ignore[attr-defined]
-except ImportError:  # pinned 0.4.x
-    from jax.experimental.shard_map import shard_map  # noqa: F401
-
 # distributed.sharding owns both the axis name and "does this mesh
 # carve it, how wide" (expert_axis_size: 0 when absent); re-exported
 # here so EP callers have one import surface.

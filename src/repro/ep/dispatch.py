@@ -40,6 +40,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from ..kernels.moe_dispatch.ops import (
@@ -55,7 +56,7 @@ from ..sched import ExpertCapacityProvider, SchedTelemetry
 from ..sched import faults
 from ..sched.executors import FinishScope
 from ..sched.faults import ShardLossError
-from .collective import EXPERT_AXIS, exchange, shard_map, token_shards
+from .collective import EXPERT_AXIS, exchange, token_shards
 from .plan import lane_capacity
 
 
@@ -216,7 +217,7 @@ def ep_dispatch_combine(p: dict, cfg, x, *, mesh, use_kernel: bool = False,
                   P(EXPERT_AXIS, None, None), P(EXPERT_AXIS, None, None),
                   P(EXPERT_AXIS, None, None)),
         out_specs=(P(EXPERT_AXIS, None), P(EXPERT_AXIS, None)),
-        check_rep=False)
+        check_vma=False)
     y, stats_rows = mapped(x, p["router"].astype(jnp.float32),
                            p["w1"], p["w3"], p["w2"])
     if not return_stats:

@@ -58,10 +58,8 @@ def _attn_kernel(q_ref, k_ref, v_ref, o_ref, *, block_k: int, seq_k: int,
 
     def body(j, carry):
         m, l, acc = carry
-        k = pl.load(k_ref, (pl.dslice(j * block_k, block_k),
-                            slice(None))).astype(jnp.float32)
-        v = pl.load(v_ref, (pl.dslice(j * block_k, block_k),
-                            slice(None))).astype(jnp.float32)
+        k = k_ref[pl.ds(j * block_k, block_k), :].astype(jnp.float32)
+        v = v_ref[pl.ds(j * block_k, block_k), :].astype(jnp.float32)
         s = jax.lax.dot_general(
             q.reshape(block_q * G, dh), k,
             (((1,), (1,)), ((), ())),

@@ -1,7 +1,7 @@
 """Jit'd public wrapper for the flash-attention kernel.
 
-On CPU (this container) the kernel runs in interpret mode; on TPU it
-compiles to Mosaic.  ``flash_attention_auto`` picks per backend.
+It compiles to Mosaic for the TPU; callers without a TPU (tests) pass
+``interpret=True``.
 """
 
 from __future__ import annotations
@@ -9,13 +9,8 @@ from __future__ import annotations
 from functools import partial
 
 import jax
-import jax.numpy as jnp
 
 from .flash_attention import flash_attention
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 @partial(jax.jit, static_argnames=("causal", "window", "block_q", "block_k",
@@ -26,9 +21,3 @@ def flash_attention_op(q, k, v, *, causal=True, window=0, block_q=128,
                            block_q=block_q, block_k=block_k,
                            interpret=interpret)
 
-
-def flash_attention_auto(q, k, v, *, causal=True, window=0,
-                         block_q=128, block_k=128):
-    return flash_attention_op(
-        q, k, v, causal=causal, window=window, block_q=block_q,
-        block_k=block_k, interpret=not _on_tpu())

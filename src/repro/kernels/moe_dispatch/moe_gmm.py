@@ -29,12 +29,9 @@ def _gmm_kernel(buf_ref, w1_ref, w3_ref, w2_ref, o_ref, *, block_f: int,
     d = x.shape[-1]
 
     def body(j, acc):
-        w1 = pl.load(w1_ref, (slice(None), pl.dslice(j * block_f, block_f))
-                     ).astype(jnp.float32)
-        w3 = pl.load(w3_ref, (slice(None), pl.dslice(j * block_f, block_f))
-                     ).astype(jnp.float32)
-        w2 = pl.load(w2_ref, (pl.dslice(j * block_f, block_f), slice(None))
-                     ).astype(jnp.float32)
+        w1 = w1_ref[:, pl.ds(j * block_f, block_f)].astype(jnp.float32)
+        w3 = w3_ref[:, pl.ds(j * block_f, block_f)].astype(jnp.float32)
+        w2 = w2_ref[pl.ds(j * block_f, block_f), :].astype(jnp.float32)
         h = jax.nn.silu(x @ w1) * (x @ w3)       # (block_c, block_f)
         return acc + h @ w2                      # (block_c, d)
 

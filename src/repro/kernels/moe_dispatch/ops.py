@@ -27,11 +27,6 @@ def moe_gmm_op(buf, w1, w3, w2, *, block_c=128, block_f=128,
                    interpret=interpret)
 
 
-def moe_gmm_auto(buf, w1, w3, w2, *, block_c=128, block_f=128):
-    return moe_gmm_op(buf, w1, w3, w2, block_c=block_c, block_f=block_f,
-                      interpret=jax.default_backend() != "tpu")
-
-
 def dispatch_tokens(x, keep, ids, pos, E: int, C: int):
     """Scatter admitted tokens into (E, C, d) buffers.
 
@@ -74,10 +69,10 @@ def expert_ffn(buf, p: dict, act: str, use_kernel: bool = False):
     E, C, d = buf.shape
     if use_kernel and act == "swiglu":
         f = p["w1"].shape[-1]
-        return moe_gmm_auto(buf, p["w1"].astype(buf.dtype),
-                            p["w3"].astype(buf.dtype),
-                            p["w2"].astype(buf.dtype),
-                            block_c=_tile(C), block_f=_tile(f))
+        return moe_gmm_op(buf, p["w1"].astype(buf.dtype),
+                          p["w3"].astype(buf.dtype),
+                          p["w2"].astype(buf.dtype),
+                          block_c=_tile(C), block_f=_tile(f))
     if act == "swiglu":
         h = jax.nn.silu(jnp.einsum("ecd,edf->ecf", buf, p["w1"])) * \
             jnp.einsum("ecd,edf->ecf", buf, p["w3"])

@@ -1,4 +1,5 @@
-"""Jit'd wrapper for the selective-scan kernel (interpret on CPU)."""
+"""Jit'd wrapper for the selective-scan kernel (tests off the TPU pass
+``interpret=True``)."""
 
 from __future__ import annotations
 
@@ -14,7 +15,3 @@ def ssm_scan_op(dA, dBx, C, *, chunk=128, block_d=256, interpret=False):
     return ssm_scan(dA, dBx, C, chunk=chunk, block_d=block_d,
                     interpret=interpret)
 
-
-def ssm_scan_auto(dA, dBx, C, *, chunk=128, block_d=256):
-    return ssm_scan_op(dA, dBx, C, chunk=chunk, block_d=block_d,
-                       interpret=jax.default_backend() != "tpu")

@@ -13,8 +13,17 @@ state (the dry-run must set XLA_FLAGS before any jax initialisation).
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 from ..distributed.sharding import EXPERT_AXIS
+
+
+def _make_mesh(shape: tuple, axes: tuple):
+    # Auto axes: the sharding code places arrays with
+    # with_sharding_constraint, which Explicit axes (jax.make_mesh's
+    # default) reject.
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False, expert: int = 0):
@@ -34,10 +43,10 @@ def make_production_mesh(*, multi_pod: bool = False, expert: int = 0):
             (expert, data // expert, 16)
         axes = ("pod", EXPERT_AXIS, "data", "model") if multi_pod else \
             (EXPERT_AXIS, "data", "model")
-        return jax.make_mesh(shape, axes)
+        return _make_mesh(shape, axes)
     shape = (2, data, 16) if multi_pod else (data, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _make_mesh(shape, axes)
 
 
 def make_test_mesh(data: int = 2, model: int = 2, pod: int = 0,
@@ -49,4 +58,4 @@ def make_test_mesh(data: int = 2, model: int = 2, pod: int = 0,
     if expert:
         shape, axes = shape + (expert,), axes + (EXPERT_AXIS,)
     shape, axes = shape + (data, model), axes + ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _make_mesh(shape, axes)

@@ -24,6 +24,7 @@ import numpy as np
 from ..configs import ARCH_IDS, get_config
 from ..models import model as MDL
 from ..serve.batcher import ContinuousBatcher, Request
+from .compile_cache import setup_compile_cache
 
 ARRIVAL_MIXES = ("steady", "bursty", "front")
 
@@ -81,6 +82,15 @@ def build_requests(args, cfg, rng) -> tuple:
     return reqs, dict(zip(names, weights))
 
 
+def build_batcher(cfg, *, seed: int, n_slots: int, cache_len: int,
+                  policy: str, tenants=None) -> ContinuousBatcher:
+    """Weights from ``seed`` and the continuous batcher that serves them."""
+    params = MDL.init_params(cfg, jax.random.PRNGKey(seed))
+    return ContinuousBatcher(cfg, params, n_slots=n_slots,
+                             cache_len=cache_len, policy=policy,
+                             tenants=tenants)
+
+
 def main(argv=None):
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--arch", required=True, choices=ARCH_IDS)
@@ -111,6 +121,7 @@ def main(argv=None):
     ap.add_argument("--metrics-interval", type=float, default=0.5,
                     help="snapshot interval in seconds for --metrics-json")
     args = ap.parse_args(argv)
+    setup_compile_cache()
 
     if args.trace:
         from ..obs import trace as obs_trace
@@ -123,12 +134,11 @@ def main(argv=None):
         snapshotter.start()
 
     cfg = get_config(args.arch, smoke=args.smoke)
-    params = MDL.init_params(cfg, jax.random.PRNGKey(args.seed))
     rng = np.random.default_rng(args.seed)
     reqs, tenants = build_requests(args, cfg, rng)
-    batcher = ContinuousBatcher(cfg, params, n_slots=args.slots,
-                                cache_len=args.cache_len,
-                                policy=args.policy, tenants=tenants)
+    batcher = build_batcher(cfg, seed=args.seed, n_slots=args.slots,
+                            cache_len=args.cache_len, policy=args.policy,
+                            tenants=tenants)
     try:
         stats = batcher.run(reqs)
     finally:
@@ -136,8 +146,11 @@ def main(argv=None):
             snapshotter.stop()
     # Fig. 10-comparable spawn/join telemetry from the slot scheduler
     telemetry = batcher.sched.telemetry.summary()
+    dev = jax.devices()[0]
     out = {
         "arch": cfg.name, "policy": batcher.policy, "steps": stats.steps,
+        "device": {"platform": dev.platform, "kind": dev.device_kind,
+                   "count": len(jax.devices())},
         "utilization": round(stats.utilization, 3),
         "mean_latency_steps": float(np.mean(stats.latencies)),
         "p99_latency_steps": float(np.percentile(stats.latencies, 99)),

@@ -15,6 +15,7 @@ from ..configs.base import ShapeConfig
 from ..train.optimizer import AdamWConfig
 from ..train.train_step import POLICIES, StepConfig
 from ..train.trainer import TrainerConfig, run_training
+from .compile_cache import setup_compile_cache
 
 
 def main(argv=None):
@@ -48,6 +49,7 @@ def main(argv=None):
     ap.add_argument("--metrics-interval", type=float, default=1.0,
                     help="snapshot interval in seconds for --metrics-json")
     args = ap.parse_args(argv)
+    setup_compile_cache()
 
     if args.trace:
         from ..obs import trace as obs_trace

@@ -303,9 +303,10 @@ class ContinuousBatcher:
         #: prefill round costs its largest chunk) — the time base of the
         #: decode-cost SLO surface
         self.vtime = 0
-        self._decode = jax.jit(
+        #: the jitted device steps this batcher launches
+        self.decode_fn = jax.jit(
             lambda p, c, b: MDL.decode_step(p, cfg, c, b))
-        self._prefill = jax.jit(
+        self.prefill_fn = jax.jit(
             lambda p, c, b: MDL.prefill_step(p, cfg, c, b))
 
     # -- admission (DLBC vs LC vs weighted-DLBC) -----------------------------
@@ -511,7 +512,7 @@ class ContinuousBatcher:
                                 {"slots": len(chunk_of),
                                  "tokens": int(sum(chunk_of.values()))}
                                 if obs.enabled() else None):
-                _, self.cache = self._prefill(
+                _, self.cache = self.prefill_fn(
                     self.params, self.cache,
                     {"tokens": jnp.asarray(tokens),
                      "cache_index": jnp.asarray(self.slot_pos, jnp.int32),
@@ -585,7 +586,7 @@ class ContinuousBatcher:
                 # from a neighbour deep into its sequence
                 # (refill-mid-decode safety).
                 cache_index = jnp.asarray(self.slot_pos, jnp.int32)
-                logits, self.cache = self._decode(
+                logits, self.cache = self.decode_fn(
                     self.params, self.cache,
                     {"tokens": jnp.asarray(tokens),
                      "cache_index": cache_index})

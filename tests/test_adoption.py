@@ -280,13 +280,18 @@ def test_moe_apply_stats_sched_vocabulary(dispatch):
     assert stats["rounds"] == (1 if dispatch == "lc" else 2)
 
 
-def test_moe_kernel_dispatch_matches_einsum_path():
-    """The Pallas grouped-matmul dispatch path (use_kernel=True,
-    interpret on CPU) agrees with the XLA einsum path."""
+def test_moe_kernel_dispatch_matches_einsum_path(monkeypatch):
+    """The Pallas grouped-matmul dispatch path (use_kernel=True, run in
+    interpret mode here) agrees with the XLA einsum path."""
     import dataclasses
+    import functools
 
     from repro.configs import get_config
+    from repro.kernels.moe_dispatch import ops as MOE_OPS
     from repro.models import moe as MOE
+
+    monkeypatch.setattr(MOE_OPS, "moe_gmm_op",
+                        functools.partial(MOE_OPS.moe_gmm_op, interpret=True))
 
     cfg = get_config("mixtral-8x7b", smoke=True)
     assert cfg.act == "swiglu"

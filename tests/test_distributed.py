@@ -27,6 +27,7 @@ SCRIPT = textwrap.dedent("""
     from repro.configs import get_config
     from repro.configs.base import ShapeConfig
     from repro.distributed.sharding import mesh_context, named_shardings
+    from repro.launch.mesh import make_test_mesh
     from repro.models import model as MDL
     from repro.roofline.analysis import collective_stats
     from repro.train.optimizer import AdamWConfig, init_opt_state
@@ -50,7 +51,7 @@ SCRIPT = textwrap.dedent("""
     ref_gnorm = float(m_ref["grad_norm"])
 
     results = {}
-    mesh = jax.make_mesh((2, 2), ("data", "model"))
+    mesh = make_test_mesh(data=2, model=2)
     for policy in ("unopt", "lc", "afe", "afe_bucket"):
         with mesh_context(mesh):
             scfg = StepConfig(policy=policy, q_chunk=32, k_chunk=32,
@@ -77,7 +78,7 @@ SCRIPT = textwrap.dedent("""
                 "colls": {k: v["count"] for k, v in stats.items()},
             }
     # --- multi-pod tiny mesh compiles ---------------------------------------
-    mesh3 = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+    mesh3 = make_test_mesh(data=2, model=2, pod=2)
     with mesh_context(mesh3):
         scfg = StepConfig(policy="afe", q_chunk=32, k_chunk=32, ssm_chunk=16)
         step, dp_shard = build_train_step(cfg, shape, scfg, ocfg)
