@@ -29,6 +29,14 @@ on): *instants* are emitted exactly where the matching
 and surface categories (``serve``/``train``/``ckpt``/``ep``) break a
 step into phases.  See ``docs/obs.md``.
 
+While enabled, every live span (:func:`trace_span`) is mirrored into
+the JAX profiler as a ``jax.profiler.TraceAnnotation`` named
+``<cat>/<name>``: under a profiler session the spans land in the
+trace's host plane, on the same clock as the device's operations.
+Instants and :func:`complete_span` are not mirrored (they are stamped
+after the fact).  JAX is imported once, by :func:`enable`, so the module
+imports without it.
+
 Environment wiring: ``REPRO_TRACE=/path/out.json`` enables tracing at
 import and registers an ``atexit`` export, so any entry point (pytest,
 launchers, benches) can be traced without code changes.
@@ -54,6 +62,10 @@ _ENABLED = False
 DEFAULT_CAPACITY = int(os.environ.get("REPRO_TRACE_CAP", 65536))
 
 _capacity = DEFAULT_CAPACITY
+
+#: ``jax.profiler.TraceAnnotation``, bound by the first :func:`enable`:
+#: the profiler-side mirror of every live span
+_Annotation = None
 
 #: ring registry: every ring ever created this process (rings of dead
 #: threads stay — their events are part of the trace).  Guarded by
@@ -134,9 +146,13 @@ def enabled() -> bool:
 def enable(capacity: Optional[int] = None):
     """Turn the tracer on process-wide.  ``capacity`` applies to rings
     created from now on (existing rings keep theirs)."""
-    global _ENABLED, _capacity
+    global _ENABLED, _capacity, _Annotation
     if capacity is not None:
         _capacity = capacity
+    if _Annotation is None:
+        from jax.profiler import TraceAnnotation
+
+        _Annotation = TraceAnnotation
     _ENABLED = True
 
 
@@ -180,7 +196,7 @@ def complete_span(cat: str, name: str, t0_ns: int,
 
 
 class _Span:
-    __slots__ = ("cat", "name", "args", "t0", "_ring")
+    __slots__ = ("cat", "name", "args", "t0", "_ring", "_mirror")
 
     def __init__(self, cat: str, name: str, args):
         self.cat = cat
@@ -190,6 +206,9 @@ class _Span:
     def __enter__(self):
         r = _ring()
         self._ring = r
+        # the profiler's copy encloses the ring's: opened first, closed last
+        self._mirror = _Annotation(self.cat + "/" + self.name)
+        self._mirror.__enter__()
         self.t0 = perf_counter_ns()
         r.open_spans.append(self)
         return self
@@ -210,6 +229,7 @@ class _Span:
         if _ENABLED:  # re-check: disable() mid-span drops the event
             _ring().emit(("X", self.t0, perf_counter_ns() - self.t0,
                           self.cat, self.name, 1, self.args))
+        self._mirror.__exit__(None, None, None)
         return False
 
 

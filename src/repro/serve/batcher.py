@@ -109,7 +109,6 @@ from ..sched.tenancy import TenantRegistry, WeightedRefillPolicy
 #: always-on metrics plane: one bump set per STEP, never per token
 _MX_SERVE_STEPS = obs_metrics.counter("serve.steps")
 _MX_QUEUE_DEPTH = obs_metrics.gauge("serve.queue_depth")
-_MX_STEP_COST = obs_metrics.gauge("serve.step_cost")
 
 
 @dataclass
@@ -126,6 +125,9 @@ class Request:
     #: failure — compared against ``RetryPolicy.attempts`` before a
     #: poisoned request is requeued instead of counted ``failed``
     attempts: int = 0
+    #: obs clock when the request last entered the queue (set only while
+    #: tracing is on): the start of its ``serve/queued`` span
+    queued_ns: Optional[int] = None
 
 
 @dataclass
@@ -196,15 +198,19 @@ class ServeStats:
 
 class _PrefillState:
     """Progress of one request's span prefill: the prompt prefix still
-    owed to the cache, a cursor, and the range latch its chunks
-    discharge into (one latch per request — the AFE join waits it)."""
+    owed to the cache, a cursor, the range latch its chunks discharge
+    into (one latch per request — the AFE join waits it), the launches
+    so far, and the obs clock at placement (``None`` untraced)."""
 
-    __slots__ = ("tokens", "cursor", "latch")
+    __slots__ = ("tokens", "cursor", "latch", "launches", "placed_ns")
 
-    def __init__(self, tokens: List[int], latch: RangeLatch):
+    def __init__(self, tokens: List[int], latch: RangeLatch,
+                 placed_ns: Optional[int]):
         self.tokens = tokens
         self.cursor = 0
         self.latch = latch
+        self.launches = 0
+        self.placed_ns = placed_ns
 
 
 class ContinuousBatcher:
@@ -345,6 +351,8 @@ class ContinuousBatcher:
                 f"family={self.cfg.family!r} "
                 f"sliding_window={self.cfg.sliding_window} is limited "
                 f"to single-token prompts")
+        if obs.enabled():
+            req.queued_ns = obs.perf_counter_ns()
         if self.registry is not None:
             self.registry.submit(req, req.tenant)
             if req.tenant not in self.tenant_stats:
@@ -373,6 +381,12 @@ class ContinuousBatcher:
             self._place(slot, req, now)
 
     def _place(self, slot: int, req: Request, now: int):
+        # lifecycle spans, one per request edge; clock reads only traced
+        if req.queued_ns is not None:
+            obs.complete_span("serve", "queued", req.queued_ns,
+                              {"rid": req.rid, "attempt": req.attempts})
+            req.queued_ns = None
+        placed_ns = obs.perf_counter_ns() if obs.enabled() else None
         req.start_step = now
         wait = now - req.arrive_step
         self.stats.queue_waits.append(wait)
@@ -396,7 +410,10 @@ class ContinuousBatcher:
         scope.add([latch])
         self.slot_scope[slot] = scope
         if prefix:
-            self._prefilling[slot] = _PrefillState(prefix, latch)
+            self._prefilling[slot] = _PrefillState(prefix, latch, placed_ns)
+        elif placed_ns is not None:
+            obs.complete_span("serve", "prompt", placed_ns,
+                              {"rid": req.rid, "tokens": 0, "launches": 0})
 
     # -- per-request containment (faults, retries, SLO deadlines) ------------
 
@@ -454,6 +471,8 @@ class ContinuousBatcher:
             r.start_step = None
             r.done_step = None
             r.tokens = []
+            if obs.enabled():
+                r.queued_ns = obs.perf_counter_ns()
             if self.registry is not None:
                 self.registry.submit(r, r.tenant)
             else:
@@ -489,25 +508,32 @@ class ContinuousBatcher:
         each prefill completely in this one step (the unchunked
         baseline).  Returns the phase's cost in token units (the largest
         chunk of each round, summed over rounds)."""
-        n_decoding = sum(1 for i, r in enumerate(self.slot_req)
-                         if r is not None and i not in self._prefilling)
         cost = 0
         while self._prefilling:
-            chunk_of: Dict[int, int] = {}
-            for i, st in self._prefilling.items():
-                rem = len(st.tokens) - st.cursor
-                if self.prefill_mode == "whole":
-                    c = min(rem, self.prefill_chunk)
-                else:
-                    c = self.sched.policy.prefill_chunk_len(
-                        rem, n_decoding, self.prefill_chunk)
-                chunk_of[i] = max(1, min(int(c), rem, self.prefill_chunk))
-            tokens = np.zeros((self.n_slots, self.prefill_chunk), np.int32)
-            counts = np.zeros(self.n_slots, np.int32)
-            for i, c in chunk_of.items():
-                st = self._prefilling[i]
-                tokens[i, :c] = st.tokens[st.cursor:st.cursor + c]
-                counts[i] = c
+            with obs.trace_span("serve", "plan"):
+                n_decoding = sum(1 for i, r in enumerate(self.slot_req)
+                                 if r is not None
+                                 and i not in self._prefilling)
+                chunk_of: Dict[int, int] = {}
+                for i, st in self._prefilling.items():
+                    rem = len(st.tokens) - st.cursor
+                    if self.prefill_mode == "whole":
+                        c = min(rem, self.prefill_chunk)
+                    else:
+                        c = self.sched.policy.prefill_chunk_len(
+                            rem, n_decoding, self.prefill_chunk)
+                    chunk_of[i] = max(1, min(int(c), rem, self.prefill_chunk))
+                tokens = np.zeros((self.n_slots, self.prefill_chunk),
+                                  np.int32)
+                counts = np.zeros(self.n_slots, np.int32)
+                for i, c in chunk_of.items():
+                    st = self._prefilling[i]
+                    tokens[i, :c] = st.tokens[st.cursor:st.cursor + c]
+                    counts[i] = c
+                # a copy: ``slot_pos`` moves on right after this launch
+                # is dispatched, and on the CPU ``jnp.asarray`` may alias
+                # the host array instead of copying it
+                cache_index = self.slot_pos.copy()
             with obs.trace_span("serve", "prefill_chunk",
                                 {"slots": len(chunk_of),
                                  "tokens": int(sum(chunk_of.values()))}
@@ -515,18 +541,25 @@ class ContinuousBatcher:
                 _, self.cache = self.prefill_fn(
                     self.params, self.cache,
                     {"tokens": jnp.asarray(tokens),
-                     "cache_index": jnp.asarray(self.slot_pos, jnp.int32),
+                     "cache_index": jnp.asarray(cache_index, jnp.int32),
                      "count": jnp.asarray(counts, jnp.int32)})
             cost += max(chunk_of.values())
             for i, c in chunk_of.items():
                 st = self._prefilling[i]
                 st.cursor += c
+                st.launches += 1
                 self.slot_pos[i] += c
                 st.latch.discharge(c)
                 self.sched.prefill(i, c)
                 if st.cursor >= len(st.tokens):
                     # prefix complete: the slot joins decode THIS step
                     del self._prefilling[i]
+                    if st.placed_ns is not None:
+                        obs.complete_span(
+                            "serve", "prompt", st.placed_ns,
+                            {"rid": self.slot_req[i].rid,
+                             "tokens": len(st.tokens),
+                             "launches": st.launches})
             if self.prefill_mode != "whole":
                 break  # chunked: one round per step, re-probe next step
         return cost
@@ -534,11 +567,12 @@ class ContinuousBatcher:
     # -- one decode step across all slots ------------------------------------
 
     def step(self, now: int):
-        # obs phases (cat="serve"): refill → prefill_chunk* → decode →
-        # complete, so a trace shows where a step's wall time goes
-        # (admission arithmetic vs span prefill vs device step vs
-        # completion bookkeeping) and slot occupancy can be read against
-        # the admit/join/prefill_chunk instants.
+        # obs phases (cat="serve"): refill → (plan → prefill_chunk)* →
+        # decode_launch → token_fetch → complete (⊃ join), so a trace
+        # shows where a step's wall time goes (admission, chunk planning,
+        # launches, the blocking wait for the device's tokens, completion
+        # bookkeeping and AFE joins) and slot occupancy can be read
+        # against the admit/join/prefill_chunk instants.
         with obs.trace_span("serve", "refill"):
             self._admit(now)
         active = [i for i, r in enumerate(self.slot_req) if r is not None]
@@ -567,7 +601,7 @@ class ContinuousBatcher:
                       if r is not None]
         if not active:
             self.vtime += 1
-            self._post_step(now, 0)
+            self._post_step(now)
             return
         prefill_cost = 0
         if self._prefilling:
@@ -575,7 +609,7 @@ class ContinuousBatcher:
         decoding = [i for i in active if i not in self._prefilling]
         step_cost = prefill_cost + (1 if decoding else 0)
         if decoding:
-            with obs.trace_span("serve", "decode",
+            with obs.trace_span("serve", "decode_launch",
                                 {"active": len(decoding)} if obs.enabled()
                                 else None):
                 tokens = np.zeros((self.n_slots, 1), np.int32)
@@ -584,18 +618,23 @@ class ContinuousBatcher:
                 # Per-slot cache positions: each slot writes/attends at
                 # ITS OWN index, so a freshly refilled slot is isolated
                 # from a neighbour deep into its sequence
-                # (refill-mid-decode safety).
-                cache_index = jnp.asarray(self.slot_pos, jnp.int32)
+                # (refill-mid-decode safety).  A copy, as for prefill:
+                # ``slot_pos`` moves on before the launch has run.
+                cache_index = jnp.asarray(self.slot_pos.copy(), jnp.int32)
                 logits, self.cache = self.decode_fn(
                     self.params, self.cache,
                     {"tokens": jnp.asarray(tokens),
                      "cache_index": cache_index})
+            with obs.trace_span("serve", "token_fetch"):
                 # argmax over the REAL vocab: the padded tail rows of the
                 # lm_head are arbitrary init values, and generated ids
                 # must stay submittable (no silent % vocab anywhere)
                 nxt = np.asarray(
                     jnp.argmax(logits[:, :self.cfg.vocab], axis=-1))
-        with obs.trace_span("serve", "complete"):
+        # ``held`` (requests in slots or queued at the end) is filled in
+        # last, so the span's args carry the state the step left
+        complete_args = {} if obs.enabled() else None
+        with obs.trace_span("serve", "complete", complete_args):
             plan = faults.active()
             for i in decoding:
                 r = self.slot_req[i]
@@ -631,8 +670,9 @@ class ContinuousBatcher:
                         # failures"; either way the slot frees and the
                         # request is contained as failed rather than
                         # crashing the serving loop.
-                        out = scope.wait(
-                            timeout=self._join_timeout_s(r.tenant))
+                        with obs.trace_span("serve", "join"):
+                            out = scope.wait(
+                                timeout=self._join_timeout_s(r.tenant))
                         if out.status != "done":
                             ok = False
                             tb = out.errors[0].tb if out.errors else None
@@ -663,16 +703,17 @@ class ContinuousBatcher:
                     self.sched.complete(slot=i)
                     self.slot_req[i] = None
                     self.slot_pos[i] = 0
+            if complete_args is not None:
+                complete_args["held"] = self.queued() + sum(
+                    r is not None for r in self.slot_req)
         self.vtime += max(1, step_cost)
-        self._post_step(now, step_cost)
+        self._post_step(now)
 
-    def _post_step(self, now: int, step_cost: int):
+    def _post_step(self, now: int):
         """Once per step: feed the always-on metrics plane and (when
         attached) the per-tenant SLO burn-rate monitor."""
         _MX_SERVE_STEPS.inc()
         _MX_QUEUE_DEPTH.set(self.queued())
-        if step_cost:
-            _MX_STEP_COST.set(step_cost)
         if self.monitor is not None:
             self.monitor.observe(self, now)
 

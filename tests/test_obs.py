@@ -65,6 +65,29 @@ def test_disabled_emit_cost_is_negligible():
     assert dt < 1.0, f"{n} disabled emits took {dt:.2f}s"
 
 
+def test_disabled_batcher_run_emits_nothing_and_reads_no_clock(monkeypatch):
+    # the serving loop's step phases and request lifecycle spans cost a
+    # flag read each while tracing is off: no event, no obs clock read
+    import jax
+
+    from repro.configs.base import ModelConfig
+    from repro.models import model as MDL
+    from repro.serve.batcher import ContinuousBatcher, Request
+
+    reads = []
+    monkeypatch.setattr(obs, "perf_counter_ns",
+                        lambda: reads.append(1) or time.perf_counter_ns())
+    cfg = ModelConfig(name="obs-off", family="dense", n_layers=1,
+                      d_model=32, n_heads=2, n_kv_heads=2, d_ff=64, vocab=64)
+    b = ContinuousBatcher(cfg, MDL.init_params(cfg, jax.random.PRNGKey(0)),
+                          n_slots=2, cache_len=64, prefill_chunk=8)
+    stats = b.run([Request(rid=0, prompt=list(range(30)), max_new=3),
+                   Request(rid=1, prompt=[1], max_new=4),
+                   Request(rid=2, prompt=[2, 3], max_new=2, arrive_step=1)])
+    assert len(stats.latencies) == 3
+    assert obs.snapshot() == [] and reads == []
+
+
 # -- enabled semantics -------------------------------------------------------
 
 def test_span_and_instant_recorded():
