@@ -57,7 +57,7 @@ def _prefill_equivalence_delta(cfg, params, seed) -> float:
     buf = 16
 
     def fill(sizes):
-        cache = MDL.init_cache(cfg, 1, 32)
+        cache = MDL.init_cache(cfg, 1, 32, MDL.lane_width(jax.devices()[0]))
         pos = 0
         for s in sizes:
             toks = np.zeros((1, buf), np.int32)

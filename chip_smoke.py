@@ -158,7 +158,7 @@ def check_prefill_decode(batcher, seed: int,
     rng = np.random.default_rng(seed + 1)
     prompt = rng.integers(0, cfg.vocab, prompt_len).astype(np.int32)
     n, width = batcher.n_slots, batcher.prefill_chunk
-    cache = MDL.init_cache(cfg, n, batcher.cache_len)
+    cache = jax.tree.map(jnp.zeros_like, batcher.cache)
     for pos in range(0, prompt_len - 1, width):
         span = prompt[pos:min(pos + width, prompt_len - 1)]
         tokens = np.zeros((n, width), np.int32)

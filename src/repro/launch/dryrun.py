@@ -71,7 +71,7 @@ def _axis_size(mesh, axes) -> int:
 def _cache_shardings(cache_tree: dict, mesh, cfg):
     """Sharding rules for decode caches (SP for long-context cells):
 
-    * KV caches (L, B, T, KV, h): B → data axes when divisible, else the
+    * KV caches (L, B, KV, T, h): B → data axes when divisible, else the
       time axis T → data (context/sequence parallelism for B=1 long_500k);
       T additionally → model when still divisible (KV heads are usually
       too few to split 16-way).
@@ -84,8 +84,8 @@ def _cache_shardings(cache_tree: dict, mesh, cfg):
 
     def leaf_spec(path, s):
         nd = s.ndim
-        if nd == 5:  # (L, B, T, KV, h)
-            _, B, T, KV, h = s.shape
+        if nd == 5:  # (L, B, KV, T, h)
+            _, B, KV, T, h = s.shape
             if B % dsize == 0:
                 b_ax, t_ax = fa, ("model" if T % msize == 0 else None)
             elif T % (dsize * msize) == 0:
@@ -94,7 +94,7 @@ def _cache_shardings(cache_tree: dict, mesh, cfg):
                 b_ax, t_ax = None, fa
             else:
                 b_ax, t_ax = None, None
-            return NamedSharding(mesh, P(None, b_ax, t_ax, None, None))
+            return NamedSharding(mesh, P(None, b_ax, None, t_ax, None))
         if nd == 4:  # ssm: (L, B, cw-1, Di) or (L, B, Di, N)
             if "conv" in path:
                 _, B, _, Di = s.shape
@@ -105,11 +105,11 @@ def _cache_shardings(cache_tree: dict, mesh, cfg):
             b_ax = fa if B % dsize == 0 else None
             d_ax = "model" if Di % msize == 0 else None
             return NamedSharding(mesh, P(None, b_ax, d_ax, None))
-        if nd == 6:  # vlm nested self stack (g, k-1, B, T, KV, h)
-            _, _, B, T, KV, h = s.shape
+        if nd == 6:  # vlm nested self stack (g, k-1, B, KV, T, h)
+            _, _, B, KV, T, h = s.shape
             b_ax = fa if B % dsize == 0 else None
             t_ax = "model" if T % msize == 0 else None
-            return NamedSharding(mesh, P(None, None, b_ax, t_ax, None, None))
+            return NamedSharding(mesh, P(None, None, b_ax, None, t_ax, None))
         return NamedSharding(mesh, P(*([None] * nd)))
 
     def walk(node, path):
@@ -167,7 +167,8 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool = False,
             fn = jax.jit(prefill, in_shardings=(pshard, bshard))
             args = (pshapes, bspecs)
         else:  # decode
-            cshapes = MDL.cache_shapes(cfg, shape.global_batch, shape.seq_len)
+            cshapes = MDL.cache_shapes(cfg, shape.global_batch, shape.seq_len,
+                                       MDL.lane_width(mesh.devices.flat[0]))
             cshard = _cache_shardings(cshapes, mesh, cfg)
             serve = build_decode_step(cfg)
             fn = jax.jit(

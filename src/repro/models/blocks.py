@@ -112,7 +112,11 @@ def layer_apply(p: dict, cfg, x: jnp.ndarray, kind: str, *,
 # ---------------------------------------------------------------------------
 
 
-def layer_cache_shapes(cfg, kind: str, B: int, cache_len: int, dtype) -> dict:
+def layer_cache_shapes(cfg, kind: str, B: int, cache_len: int, dtype,
+                       lane: int) -> dict:
+    """One layer's decode cache.  Self-attention K/V are heads-major
+    ``(B, KV, T, hp)``, the head dim padded up to a multiple of ``lane``
+    (see ``model.cache_shapes``)."""
     out = {}
     h, KV = cfg.head_dim, cfg.n_kv_heads
     if kind in ("dense", "moe", "hybrid", "dec", "cross"):
@@ -121,17 +125,30 @@ def layer_cache_shapes(cfg, kind: str, B: int, cache_len: int, dtype) -> dict:
         # Windowed archs only materialise the window (ring buffer) — this is
         # what keeps mixtral/hymba long_500k caches small.
         if kind != "cross":
-            out["k"] = jax.ShapeDtypeStruct((B, T, KV, h), dtype)
-            out["v"] = jax.ShapeDtypeStruct((B, T, KV, h), dtype)
+            hp = -(-h // lane) * lane
+            out["k"] = jax.ShapeDtypeStruct((B, KV, T, hp), dtype)
+            out["v"] = jax.ShapeDtypeStruct((B, KV, T, hp), dtype)
     if kind in ("ssm", "hybrid"):
         out.update(S.ssm_cache_shapes(cfg, B, dtype))
     return out
 
 
+def _ssm_decode(p: dict, cfg, h: jnp.ndarray, cache: dict, at: tuple):
+    """Mamba decode on layer ``at`` of the stacked cache.  Recurrent state
+    is small, so it keeps the slice → update → write-back path."""
+    state = {k: L.layer_of(cache[k], at) for k in ("conv", "h")}
+    y, new = S.ssm_decode_apply(p, cfg, h, state)
+    return y, {k: L.put_layer(cache[k], new[k], at) for k in new}
+
+
 def layer_decode_apply(p: dict, cfg, x: jnp.ndarray, cache: dict,
-                       cache_index, kind: str, *,
+                       cache_index, kind: str, *, at: tuple = (),
                        ctx_kv: Optional[dict] = None):
-    """One block, one token.  Returns (x, new_cache).
+    """One block, one token, on layer ``at`` of the stacked cache ``cache``
+    (``at`` = the layer's leading indices; ``()`` when ``cache`` is one
+    layer).  Returns ``(x, new_cache)``, the whole stack: attention
+    writes its token's K/V into the stack at ``[*at, row, :, position]``
+    and reads the layer from there.
 
     For windowed caches the write index wraps (ring buffer) and the
     attention window covers the whole buffer.
@@ -144,17 +161,16 @@ def layer_decode_apply(p: dict, cfg, x: jnp.ndarray, cache: dict,
     new_cache = dict(cache)
     if kind == "ssm":
         h = L.norm_apply(p["ln1"], x, cfg.norm)
-        y, sc = S.ssm_decode_apply(p["ssm"], cfg, h, cache)
+        y, sc = _ssm_decode(p["ssm"], cfg, h, cache, at)
         new_cache.update(sc)
         return x + y, new_cache
     h = L.norm_apply(p["ln1"], x, cfg.norm)
     if kind == "cross":
         a = L.cross_decode_apply(p["attn"], cfg, h, ctx_kv)
     else:
-        T = cache["k"].shape[1]
+        T = cache["k"].shape[len(at) + 2]
         ci = jnp.asarray(cache_index, jnp.int32)
         idx = jnp.mod(ci, T) if cfg.sliding_window > 0 else ci
-        window = 0 if cfg.sliding_window > 0 else 0  # ring buffer = window
         # In the ring buffer every entry is valid once full; effective
         # index for masking is min(cache_index+1, T).
         p_attn = p["attn"]
@@ -168,14 +184,15 @@ def layer_decode_apply(p: dict, cfg, x: jnp.ndarray, cache: dict,
             pos = L.decode_positions(ci, x.shape[0])
             q = L.apply_rope(q, pos, cfg.rope_theta)
             k = L.apply_rope(k, pos, cfg.rope_theta)
-        kc = L.kv_cache_update(cache["k"], k, idx)
-        vc = L.kv_cache_update(cache["v"], v, idx)
+        kc = L.kv_cache_update(cache["k"], k, idx, at)
+        vc = L.kv_cache_update(cache["v"], v, idx, at)
         valid = jnp.minimum(ci + 1, T)
-        a = L.decode_attention(q, kc, vc, valid, window=0)
+        a = L.decode_attention(q, L.layer_of(kc, at), L.layer_of(vc, at),
+                               valid, window=0)
         a = L.dense_apply(p_attn["wo"], a.reshape(x.shape[0], 1, -1))
         new_cache["k"], new_cache["v"] = kc, vc
     if kind == "hybrid":
-        y, sc = S.ssm_decode_apply(p["ssm"], cfg, h, cache)
+        y, sc = _ssm_decode(p["ssm"], cfg, h, cache, at)
         a = (a + y) * 0.5
         new_cache.update(sc)
     x = x + a
@@ -191,8 +208,9 @@ def layer_decode_apply(p: dict, cfg, x: jnp.ndarray, cache: dict,
 
 
 def layer_prefill_apply(p: dict, cfg, x: jnp.ndarray, cache: dict,
-                        cache_index, count, kind: str):
-    """One block over a ``(B, C)`` token span (chunked prefill).
+                        cache_index, count, kind: str, *, at: tuple):
+    """One block over a ``(B, C)`` token span (chunked prefill), on layer
+    ``at`` of the stacked cache as in :func:`layer_decode_apply`.
     Returns ``(x, new_cache)``.
 
     Only full-cache attention families are supported: recurrent state
@@ -207,7 +225,7 @@ def layer_prefill_apply(p: dict, cfg, x: jnp.ndarray, cache: dict,
     new_cache = dict(cache)
     h = L.norm_apply(p["ln1"], x, cfg.norm)
     a, kc, vc = L.attn_prefill_apply(p["attn"], cfg, h, cache,
-                                     cache_index, count)
+                                     cache_index, count, at)
     new_cache["k"], new_cache["v"] = kc, vc
     x = x + a
     h = L.norm_apply(p["ln2"], x, cfg.norm)

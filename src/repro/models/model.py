@@ -215,36 +215,65 @@ def loss_fn(params, cfg, batch, **kw):
 # ---------------------------------------------------------------------------
 
 
-def cache_shapes(cfg: ModelConfig, bsz: int, cache_len: int) -> dict:
+#: minor-dim tile of each backend's array layout, in elements (CPU and
+#: others: none).  See :func:`cache_shapes`.
+_LANE_WIDTH = {"tpu": 128}
+
+
+def lane_width(device) -> int:
+    """The lane width of ``device``'s layouts, for :func:`cache_shapes`."""
+    return _LANE_WIDTH.get(device.platform, 1)
+
+
+def cache_shapes(cfg: ModelConfig, bsz: int, cache_len: int,
+                 lane: int) -> dict:
+    """The decode cache: each stack's per-layer caches stacked on a
+    leading ``(L,)`` dim, plus ``cross_kv`` for encdec/vlm.
+
+    K/V are heads-major, ``(L, bsz, KV, T, h)``: each head's ``(T, h)``
+    rows are the minor pair that the attention dots read.  Self-attention
+    K/V pad the head dim up to a multiple of ``lane``, the lane width of
+    the device that runs the steps (:func:`lane_width`), so the device's
+    default layout for the cache keeps ``h`` minor, as the layer body
+    writes and reads it, and no launch converts the cache's layout.
+    With ``lane=1`` (CPU), or a head dim already a multiple, ``h`` is
+    not padded.  ``cross_kv`` is written once and never padded.  The
+    lane has no default: a cache compiled for one device and served on
+    another would lay out differently from what that device runs.
+
+    The serving steps (``serve.batcher.step_programs``) donate the cache
+    they are given and write it in place: a caller keeps the cache each
+    launch returns and never reuses the one it passed."""
     dt = _dtype(cfg)
     out = {}
     for name, kind, n, inner in _plan(cfg):
         if kind == "enc":
             continue
-        s = B.layer_cache_shapes(cfg, kind, bsz, cache_len, dt)
+        s = B.layer_cache_shapes(cfg, kind, bsz, cache_len, dt, lane)
         s = _stack_shapes(s, inner) if inner else s
         out[name] = _stack_shapes(s, n)
     if cfg.family == "encdec":
         h, KV = cfg.head_dim, cfg.n_kv_heads
         out["cross_kv"] = {
             "k": jax.ShapeDtypeStruct(
-                (cfg.n_layers, bsz, cfg.enc_seq, KV, h), dt),
+                (cfg.n_layers, bsz, KV, cfg.enc_seq, h), dt),
             "v": jax.ShapeDtypeStruct(
-                (cfg.n_layers, bsz, cfg.enc_seq, KV, h), dt),
+                (cfg.n_layers, bsz, KV, cfg.enc_seq, h), dt),
         }
     if cfg.family == "vlm":
         h, KV = cfg.head_dim, cfg.n_kv_heads
         g = cfg.n_layers // cfg.cross_every
         out["cross_kv"] = {
-            "k": jax.ShapeDtypeStruct((g, bsz, cfg.vis_seq, KV, h), dt),
-            "v": jax.ShapeDtypeStruct((g, bsz, cfg.vis_seq, KV, h), dt),
+            "k": jax.ShapeDtypeStruct((g, bsz, KV, cfg.vis_seq, h), dt),
+            "v": jax.ShapeDtypeStruct((g, bsz, KV, cfg.vis_seq, h), dt),
         }
     return out
 
 
-def init_cache(cfg: ModelConfig, bsz: int, cache_len: int) -> dict:
+def init_cache(cfg: ModelConfig, bsz: int, cache_len: int,
+               lane: int) -> dict:
     return jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype),
-                        cache_shapes(cfg, bsz, cache_len))
+                        cache_shapes(cfg, bsz, cache_len, lane))
 
 
 def _idx(tree, i):
@@ -253,27 +282,24 @@ def _idx(tree, i):
         tree)
 
 
-def _dus(tree, upd, i):
-    return jax.tree.map(
-        lambda a, u: jax.lax.dynamic_update_index_in_dim(
-            a, u.astype(a.dtype), i, 0),
-        tree, upd)
+def _decode_scan(stack_params, cache_stack, x, step_fn, outer: tuple = ()):
+    """Scan over layers carrying the FULL stacked cache.
 
-
-def _decode_scan(stack_params, cache_stack, x, step_fn):
-    """Scan over layers carrying the FULL cache and updating it in place
-    (dynamic-update-slice on the carry).  Unlike an xs→ys scan this keeps
-    a single cache buffer alive — the xs input + stacked ys output pattern
-    double-buffered multi-GB KV caches (phi3 decode_32k: 15.5 GB temp →
-    §Perf iteration 4)."""
+    ``step_fn(x, layer_params, cache_stack, at)`` runs layer ``at`` =
+    ``outer + (l,)`` (its leading indices in the stack) and returns
+    ``(x, cache_stack)``.  Attention K/V are written straight into the
+    carried stack at ``[*at, rows, positions]`` and read from it there:
+    no per-layer slice is built or written back, so a launch that
+    donates the cache writes only the positions it produces, in place.
+    Recurrent state (ssm/hybrid) is small and keeps a per-layer slice
+    and write-back.  A single carried buffer also avoids the xs→ys
+    double buffering of multi-GB caches (phi3 decode_32k: 15.5 GB temp
+    → §Perf iteration 4)."""
     n = jax.tree.leaves(stack_params)[0].shape[0]
 
     def body(carry, l):
         x, cache = carry
-        lp = _idx(stack_params, l)
-        lc = _idx(cache, l)
-        x, nc = step_fn(x, lp, lc, l)
-        cache = _dus(cache, nc, l)
+        x, cache = step_fn(x, _idx(stack_params, l), cache, outer + (l,))
         return (x, cache), None
 
     (x, cache_stack), _ = jax.lax.scan(
@@ -288,6 +314,11 @@ def decode_step(params: dict, cfg: ModelConfig, cache: dict,
     batch = {"tokens": (B, 1), "cache_index": () or (B,)} — returns
     (logits (B, vocab), new_cache).  A per-row cache index lets the
     continuous batcher keep each decode slot at its own position.
+
+    Each layer writes one K/V position per row into the stacked cache
+    (``[l, row, :, cache_index[row]]``, modulo T for a ring buffer), so a
+    caller that donates ``cache`` gets it back updated in place and must
+    not reuse the array it passed.
     """
     tokens, cache_index = batch["tokens"], batch["cache_index"]
     x = jnp.take(params["embed"], tokens, axis=0)
@@ -299,30 +330,28 @@ def decode_step(params: dict, cfg: ModelConfig, cache: dict,
                    "cross": params["cross_layers"]}
         g = jax.tree.leaves(params["cross_layers"])[0].shape[0]
 
+        def self_step(xx, lp, c, at):
+            return B.layer_decode_apply(lp, cfg, xx, c, cache_index,
+                                        "dense", at=at)
+
         def gbody(carry, gi):
             x, self_cache = carry
             gp = _idx(gp_tree, gi)
-            gcache = _idx(self_cache, gi)
-
-            def self_step(xx, lp, lc, _l):
-                return B.layer_decode_apply(lp, cfg, xx, lc, cache_index,
-                                            "dense")
-
-            x, gcache = _decode_scan(gp["self"], gcache, x, self_step)
+            x, self_cache = _decode_scan(gp["self"], self_cache, x,
+                                         self_step, outer=(gi,))
             x, _ = B.layer_decode_apply(
                 gp["cross"], cfg, x, {}, cache_index, "cross",
                 ctx_kv=_idx(cache["cross_kv"], gi))
-            self_cache = _dus(self_cache, gcache, gi)
             return (x, self_cache), None
 
         (x, new_self), _ = jax.lax.scan(
             gbody, (x, cache["self_layers"]), jnp.arange(g))
         new_cache["self_layers"] = new_self
     elif cfg.family == "encdec":
-        def dec_step(xx, lp, lc, l):
+        def dec_step(xx, lp, c, at):
             return B.layer_decode_apply(
-                lp, cfg, xx, lc, cache_index, "dec",
-                ctx_kv=_idx(cache["cross_kv"], l))
+                lp, cfg, xx, c, cache_index, "dec", at=at,
+                ctx_kv=_idx(cache["cross_kv"], at[0]))
 
         x, new_dec = _decode_scan(params["dec_layers"],
                                   cache["dec_layers"], x, dec_step)
@@ -330,8 +359,9 @@ def decode_step(params: dict, cfg: ModelConfig, cache: dict,
     else:
         kind = _plan(cfg)[0][1]
 
-        def lyr_step(xx, lp, lc, _l):
-            return B.layer_decode_apply(lp, cfg, xx, lc, cache_index, kind)
+        def lyr_step(xx, lp, c, at):
+            return B.layer_decode_apply(lp, cfg, xx, c, cache_index, kind,
+                                        at=at)
 
         x, new_layers = _decode_scan(params["layers"], cache["layers"], x,
                                      lyr_step)
@@ -366,6 +396,10 @@ def prefill_step(params: dict, cfg: ModelConfig, cache: dict,
     prefill is bitwise identical to whole-prompt prefill (pinned by
     tests/test_prefill.py).
 
+    Like :func:`decode_step`, each layer writes only the span's valid
+    positions into the stacked cache, in place when the caller donates
+    it (the caller must not reuse the array it passed).
+
     Only full-cache attention families (dense/moe, no sliding window)
     are supported — recurrent and ring-buffer caches have no
     position-indexed span write.
@@ -386,9 +420,9 @@ def prefill_step(params: dict, cfg: ModelConfig, cache: dict,
     new_cache = dict(cache)
     kind = _plan(cfg)[0][1]
 
-    def lyr_step(xx, lp, lc, _l):
-        return B.layer_prefill_apply(lp, cfg, xx, lc, cache_index, count,
-                                     kind)
+    def lyr_step(xx, lp, c, at):
+        return B.layer_prefill_apply(lp, cfg, xx, c, cache_index, count,
+                                     kind, at=at)
 
     x, new_layers = _decode_scan(params["layers"], cache["layers"], x,
                                  lyr_step)

@@ -111,6 +111,17 @@ _MX_SERVE_STEPS = obs_metrics.counter("serve.steps")
 _MX_QUEUE_DEPTH = obs_metrics.gauge("serve.queue_depth")
 
 
+def step_programs(cfg: ModelConfig) -> tuple:
+    """The jitted ``(decode, prefill)`` steps, each called as
+    ``fn(params, cache, batch) -> (logits, cache)``.  Both DONATE the
+    cache (argument 1) and write it in place: the array passed in is
+    invalid afterwards, and the caller keeps the one returned."""
+    return (jax.jit(lambda p, c, b: MDL.decode_step(p, cfg, c, b),
+                    donate_argnums=(1,)),
+            jax.jit(lambda p, c, b: MDL.prefill_step(p, cfg, c, b),
+                    donate_argnums=(1,)))
+
+
 @dataclass
 class Request:
     rid: int
@@ -287,7 +298,10 @@ class ContinuousBatcher:
                     self.registry.get(name).slo_steps = int(slo)
                 except KeyError:
                     pass
-        self.cache = MDL.init_cache(cfg, n_slots, cache_len)
+        # K/V pad their head dim to the lane width of the device the
+        # steps run on (``model.cache_shapes``)
+        self.cache = MDL.init_cache(cfg, n_slots, cache_len,
+                                    MDL.lane_width(jax.devices()[0]))
         self.slot_req: List[Optional[Request]] = [None] * n_slots
         self.slot_pos = np.zeros(n_slots, np.int32)
         #: one FinishScope per in-flight request, spanning all its
@@ -309,11 +323,9 @@ class ContinuousBatcher:
         #: prefill round costs its largest chunk) — the time base of the
         #: decode-cost SLO surface
         self.vtime = 0
-        #: the jitted device steps this batcher launches
-        self.decode_fn = jax.jit(
-            lambda p, c, b: MDL.decode_step(p, cfg, c, b))
-        self.prefill_fn = jax.jit(
-            lambda p, c, b: MDL.prefill_step(p, cfg, c, b))
+        #: the jitted device steps this batcher launches; both donate the
+        #: cache, and ``self.cache`` is rebound to each launch's result
+        self.decode_fn, self.prefill_fn = step_programs(cfg)
 
     # -- admission (DLBC vs LC vs weighted-DLBC) -----------------------------
 

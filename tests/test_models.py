@@ -59,7 +59,7 @@ def test_arch_forward_shapes_and_finite(arch):
 def test_arch_decode_step(arch):
     cfg = get_config(arch, smoke=True)
     params = MDL.init_params(cfg, jax.random.PRNGKey(0))
-    cache = MDL.init_cache(cfg, B, 64)
+    cache = MDL.init_cache(cfg, B, 64, 1)
     serve = build_decode_step(cfg)
     batch = {"tokens": jnp.ones((B, 1), jnp.int32),
              "cache_index": jnp.asarray(3, jnp.int32)}
@@ -81,7 +81,7 @@ def test_decode_matches_forward_dense():
     tokens = jax.random.randint(jax.random.PRNGKey(1), (1, T), 0, cfg.vocab)
     full = MDL.forward(params, cfg, {"tokens": tokens}, q_chunk=8,
                        k_chunk=8, remat=False).astype(jnp.float32)
-    cache = MDL.init_cache(cfg, 1, 16)
+    cache = MDL.init_cache(cfg, 1, 16, 1)
     outs = []
     for t in range(T):
         logits, cache = MDL.decode_step(
@@ -105,7 +105,7 @@ def test_decode_matches_forward_ssm():
     tokens = jax.random.randint(jax.random.PRNGKey(1), (1, T), 0, cfg.vocab)
     full = MDL.forward(params, cfg, {"tokens": tokens}, ssm_chunk=4,
                        remat=False).astype(jnp.float32)
-    cache = MDL.init_cache(cfg, 1, 16)
+    cache = MDL.init_cache(cfg, 1, 16, 1)
     outs = []
     for t in range(T):
         logits, cache = MDL.decode_step(

@@ -40,12 +40,12 @@ def setup():
 
 
 def _prefill_in_chunks(cfg, params, prompt, sizes, *, buf=16, bsz=2,
-                       cache_len=32):
+                       cache_len=32, lane=1):
     """Write ``prompt`` through prefill_step in the given chunk sizes
     (row 0 live, row 1 inert), all through one static ``buf``-wide
-    launch buffer like the batcher."""
+    launch buffer like the batcher, into a cache padded to ``lane``."""
     assert sum(sizes) == len(prompt) and max(sizes) <= buf
-    cache = MDL.init_cache(cfg, bsz, cache_len)
+    cache = MDL.init_cache(cfg, bsz, cache_len, lane)
     pos = 0
     for s in sizes:
         toks = np.zeros((bsz, buf), np.int32)
@@ -69,16 +69,21 @@ def _decode_logits(cfg, params, cache, token, pos, bsz=2):
     return np.asarray(logits)
 
 
-def test_chunked_prefill_is_bitwise_equal_to_whole(setup):
+@pytest.mark.parametrize("lane", [1, 128])
+def test_chunked_prefill_is_bitwise_equal_to_whole(setup, lane):
     """Chunk size ∈ {1, 8, prompt_len}: the KV cache and the next-token
-    logits are EXACTLY equal — max |Δ| == 0.0, not allclose."""
+    logits are EXACTLY equal — max |Δ| == 0.0, not allclose — on the
+    cache as the CPU lays it out and padded to a TPU's 128 lanes."""
     cfg, params = setup
     rng = np.random.default_rng(7)
     prompt = rng.integers(0, cfg.vocab, size=12).tolist()
     pre = len(prompt) - 1  # decode consumes the last prompt token
-    whole = _prefill_in_chunks(cfg, params, prompt[:-1], [pre])
-    by_one = _prefill_in_chunks(cfg, params, prompt[:-1], [1] * pre)
-    by_eight = _prefill_in_chunks(cfg, params, prompt[:-1], [8, pre - 8])
+    chunks = lambda sizes: _prefill_in_chunks(  # noqa: E731
+        cfg, params, prompt[:-1], sizes, lane=lane)
+    whole = chunks([pre])
+    assert whole["layers"]["k"].shape[-1] == max(cfg.head_dim, lane)
+    by_one = chunks([1] * pre)
+    by_eight = chunks([8, pre - 8])
     ref = _decode_logits(cfg, params, whole, prompt[-1], pre)
     for cache in (by_one, by_eight):
         for k in ("k", "v"):
@@ -110,7 +115,7 @@ def test_inert_rows_untouched_bitwise(setup):
     bit-for-bit — seeded with garbage first so zeros can't mask a
     spurious write."""
     cfg, params = setup
-    cache = MDL.init_cache(cfg, 2, 32)
+    cache = MDL.init_cache(cfg, 2, 32, 1)
     k0 = jax.random.normal(jax.random.PRNGKey(1),
                            cache["layers"]["k"].shape,
                            cache["layers"]["k"].dtype)
@@ -125,8 +130,51 @@ def test_inert_rows_untouched_bitwise(setup):
     assert np.array_equal(np.asarray(new_cache["layers"]["k"])[:, 1],
                           np.asarray(k0)[:, 1])
     # and the live row's tail (past its span) is untouched too
-    assert np.array_equal(np.asarray(new_cache["layers"]["k"])[:, 0, 5:],
-                          np.asarray(k0)[:, 0, 5:])
+    assert np.array_equal(np.asarray(new_cache["layers"]["k"])[:, 0, :, 5:],
+                          np.asarray(k0)[:, 0, :, 5:])
+
+
+def _span_reference(cache, new, idx, count):
+    """The span write's contract, one position at a time: lane j of row
+    b lands at ``[b, :, idx[b] + j]``, zero-padded past the head dim,
+    when ``j < count[b]`` and the position is inside the cache; every
+    other position keeps its bits."""
+    out = np.array(cache)
+    T, h = out.shape[2], new.shape[3]
+    for b in range(out.shape[0]):
+        for j in range(min(int(count[b]), new.shape[1])):
+            if int(idx[b]) + j < T:
+                out[b, :, int(idx[b]) + j] = 0
+                out[b, :, int(idx[b]) + j, :h] = new[b, j]
+    return out
+
+
+@pytest.mark.parametrize("T,C,idx,count", [
+    (16, 8, [0, 5], [8, 3]),      # spans inside the cache
+    (16, 8, [12, 0], [8, 0]),     # runs past the end; an inert row
+    (16, 8, [15, 9], [1, 7]),     # the window clamps to the cache end
+    (8, 12, [2, 0], [12, 8]),     # a launch wider than the cache
+])
+def test_span_write_keeps_every_dropped_position(T, C, idx, count):
+    """``kv_cache_update_span`` equals the one-position reference
+    bitwise, on a cache seeded with noise and padded past the head dim,
+    whether it is one layer or layer 1 of a stack."""
+    from repro.models import layers as L
+
+    B, KV, h, dc = 2, 3, 4, 8
+    rng = np.random.default_rng(T * C)
+    cache = rng.normal(size=(B, KV, T, dc)).astype(np.float32)
+    new = rng.normal(size=(B, C, KV, h)).astype(np.float32)
+    idx, count = np.asarray(idx, np.int32), np.asarray(count, np.int32)
+    want = _span_reference(cache, new, idx, count)
+    got = L.kv_cache_update_span(jnp.asarray(cache), jnp.asarray(new),
+                                 jnp.asarray(idx), jnp.asarray(count))
+    assert np.array_equal(np.asarray(got), want)
+    stack = jnp.stack([jnp.zeros_like(cache), jnp.asarray(cache)])
+    got = L.kv_cache_update_span(stack, jnp.asarray(new), jnp.asarray(idx),
+                                 jnp.asarray(count), at=(jnp.int32(1),))
+    assert np.array_equal(np.asarray(got[1]), want)
+    assert not np.any(np.asarray(got[0]))
 
 
 def test_prefill_rejected_for_unsupported_cache_families(setup):
@@ -136,7 +184,7 @@ def test_prefill_rejected_for_unsupported_cache_families(setup):
                            vocab=128, sliding_window=8)
     with pytest.raises(NotImplementedError, match="ring-buffer"):
         MDL.prefill_step(MDL.init_params(windowed, jax.random.PRNGKey(0)),
-                         windowed, MDL.init_cache(windowed, 1, 16),
+                         windowed, MDL.init_cache(windowed, 1, 16, 1),
                          {"tokens": jnp.zeros((1, 4), jnp.int32),
                           "cache_index": jnp.zeros(1, jnp.int32),
                           "count": jnp.ones(1, jnp.int32)})
@@ -177,6 +225,38 @@ def test_refill_mid_prefill_neighbour_decode_unperturbed(setup):
     assert both.sched.telemetry.prefill_chunks >= 4
     assert s.tokens == solo_s.tokens
     assert a.tokens == solo_a.tokens
+
+
+def test_padded_cache_serves_the_same_tokens(setup, monkeypatch):
+    """A batcher whose cache is padded to a TPU's 128 lanes serves
+    exactly the tokens of one with the CPU's unpadded cache, through a
+    refill mid-decode and a prompt spread over several DLBC chunks —
+    and each launch leaves the cache it was given donated."""
+    cfg, params = setup
+    rng = np.random.default_rng(17)
+    long_prompt = rng.integers(0, cfg.vocab, size=19).tolist()
+
+    def serve(lane):
+        monkeypatch.setattr(MDL, "lane_width", lambda device: lane)
+        b = ContinuousBatcher(cfg, params, n_slots=2, cache_len=48,
+                              policy="dlbc", prefill_chunk=4)
+        assert b.cache["layers"]["k"].shape[-1] == max(cfg.head_dim, lane)
+        first = b.cache["layers"]["k"]
+        reqs = [Request(rid=0, prompt=[3, 1, 4], max_new=5, arrive_step=0),
+                Request(rid=1, prompt=[5, 9], max_new=14, arrive_step=0),
+                # refills the first slot to free while rid 1 decodes
+                Request(rid=2, prompt=list(long_prompt), max_new=6,
+                        arrive_step=2)]
+        b.run(reqs)
+        assert first.is_deleted()
+        return b, [r.tokens for r in reqs]
+
+    padded, padded_tokens = serve(128)
+    plain, plain_tokens = serve(1)
+    assert padded.sched.telemetry.prefill_chunks >= 5
+    assert [a[0] for a in padded.admissions] == [0, 0, 5]
+    assert padded.admissions == plain.admissions
+    assert padded_tokens == plain_tokens
 
 
 def test_submit_rejects_empty_prompt(setup):

@@ -92,7 +92,8 @@ def test_phi3_decode_step_fits_one_chip(one_chip):
 
     params = place(jax.eval_shape(
         lambda: MDL.init_params(cfg, jax.random.PRNGKey(0))))
-    cache = place(jax.eval_shape(lambda: MDL.init_cache(cfg, 4, 1024)))
+    lane = MDL.lane_width(next(iter(one_chip.device_set)))
+    cache = place(jax.eval_shape(lambda: MDL.init_cache(cfg, 4, 1024, lane)))
     batch = {"tokens": _shape(one_chip, (4, 1), jnp.int32),
              "cache_index": _shape(one_chip, (4,), jnp.int32)}
     compiled = jax.jit(
@@ -102,3 +103,91 @@ def test_phi3_decode_step_fits_one_chip(one_chip):
     used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes)
     assert used < 16e9, used
+
+
+def _holds_layer(dims, slots, T, KV) -> bool:
+    """Whether a shape holds a whole layer's K or V: slots, T and KV
+    among its dims, in any order and layout."""
+    rest = list(dims)
+    for d in (slots, T, KV):
+        if d not in rest:
+            return False
+        rest.remove(d)
+    return True
+
+
+def _layer_sized_moves(hlo: str, slots: int, T: int, KV: int) -> list:
+    """The ops of an optimized module that write a whole layer's or the
+    whole cache's K/V: a copy or transpose whose result holds one, or a
+    dynamic-update-slice or scatter whose UPDATE holds one (an in-place
+    update's result is the whole buffer whatever it writes)."""
+    import re
+
+    define = re.compile(r"\s*(?:ROOT )?%(\S+) = \w+\[([\d,]*)\]\S* "
+                        r"([\w-]+)\(([^)]*)\)")
+    ops = []
+    shapes = {}
+    for line in hlo.splitlines():
+        m = define.match(line)
+        if m:
+            name, dims, opcode, args = m.groups()
+            shapes[name] = [int(d) for d in dims.split(",") if d]
+            ops.append((name, opcode, re.findall(r"%(\S+?)(?:[,)\s]|$)",
+                                                 args + ")")))
+    update_arg = {"dynamic-update-slice": 1, "scatter": 2}
+    moves = []
+    for name, opcode, args in ops:
+        if opcode in ("copy", "transpose"):
+            dims = shapes[name]
+        elif opcode in update_arg and len(args) > update_arg[opcode]:
+            dims = shapes.get(args[update_arg[opcode]], [])
+        else:
+            continue
+        if _holds_layer(dims, slots, T, KV):
+            moves.append(f"{opcode} {name} {dims}")
+    return moves
+
+
+def test_phi3_step_programs_write_the_cache_in_place(one_chip):
+    """phi3's decode (4 slots x 1024) and prefill (chunks of 32) programs,
+    jitted as ``ContinuousBatcher`` jits them and with the cache sized for
+    the described chip's lane width, write only the positions they
+    produce: no op copies, transposes or writes back a whole layer's or
+    the whole cache's K/V, and the donated cache is aliased to the
+    output."""
+    from repro.configs import get_config
+    from repro.models import model as MDL
+    from repro.serve.batcher import step_programs
+
+    cfg = get_config("phi3-mini-3.8b")
+    slots, T, chunk = 4, 1024, 32
+    lane = MDL.lane_width(next(iter(one_chip.device_set)))
+    assert lane == 128
+
+    def place(tree):
+        return jax.tree.map(lambda s: _shape(one_chip, s.shape, s.dtype),
+                            tree)
+
+    params = place(jax.eval_shape(
+        lambda: MDL.init_params(cfg, jax.random.PRNGKey(0))))
+    cache = place(jax.eval_shape(
+        lambda: MDL.init_cache(cfg, slots, T, lane)))
+    assert cache["layers"]["k"].shape == (cfg.n_layers, slots,
+                                          cfg.n_kv_heads, T, 128)
+    cache_bytes = sum(s.size * s.dtype.itemsize
+                      for s in jax.tree.leaves(cache))
+    ints = lambda *s: _shape(one_chip, s, jnp.int32)  # noqa: E731
+    decode_fn, prefill_fn = step_programs(cfg)
+    for fn, batch in (
+            (decode_fn, {"tokens": ints(slots, 1),
+                         "cache_index": ints(slots)}),
+            (prefill_fn, {"tokens": ints(slots, chunk),
+                          "cache_index": ints(slots),
+                          "count": ints(slots)})):
+        compiled = fn.lower(params, cache, batch).compile()
+        moves = _layer_sized_moves(compiled.as_text(), slots, T,
+                                   cfg.n_kv_heads)
+        assert not moves, moves
+        mem = compiled.memory_analysis()
+        assert mem.alias_size_in_bytes >= cache_bytes, (
+            mem.alias_size_in_bytes, cache_bytes)
