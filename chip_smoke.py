@@ -163,13 +163,13 @@ def check_prefill_decode(batcher, seed: int,
         span = prompt[pos:min(pos + width, prompt_len - 1)]
         tokens = np.zeros((n, width), np.int32)
         tokens[0, :len(span)] = span
-        _, cache = batcher.prefill_fn(params, cache, {
+        _, cache, _ = batcher.prefill_fn(params, cache, {
             "tokens": jnp.asarray(tokens),
             "cache_index": jnp.asarray([pos] + [0] * (n - 1), jnp.int32),
             "count": jnp.asarray([len(span)] + [0] * (n - 1), jnp.int32)})
     tokens = np.zeros((n, 1), np.int32)
     tokens[0, 0] = prompt[-1]
-    logits, _ = batcher.decode_fn(params, cache, {
+    logits, _, _ = batcher.decode_fn(params, cache, {
         "tokens": jnp.asarray(tokens),
         "cache_index": jnp.asarray([prompt_len - 1] + [0] * (n - 1),
                                    jnp.int32)})

@@ -18,7 +18,7 @@ import jax.numpy as jnp
 @dataclass(frozen=True)
 class ModelConfig:
     name: str
-    family: str  # dense | moe | ssm | hybrid | encdec | vlm
+    family: str  # dense | moe | ssm | hybrid | encdec | vlm | mla_moe
     n_layers: int
     d_model: int
     n_heads: int
@@ -35,6 +35,22 @@ class ModelConfig:
     #: when the mesh carves an "expert" axis that divides E and T,
     #: otherwise falls back to the single-host dispatch path
     expert_parallel: bool = False
+    #: the factor on the chosen experts' normalised gates (mla_moe, whose
+    #: router is DeepSeek-V3's sigmoid with a correction bias)
+    routed_scale: float = 1.0
+    #: shared experts, each of width ``d_ff``, computed for every token
+    #: as one SwiGLU of width ``n_shared_experts * d_ff``
+    n_shared_experts: int = 0
+    #: leading dense layers (MLP of width ``dense_d_ff``) before the MoE
+    #: layers (mla_moe)
+    first_k_dense: int = 0
+    dense_d_ff: int = 0
+    #: the experts this chip holds, ``expert_first ..
+    #: expert_first + experts_held - 1`` of the ``n_experts`` the router
+    #: scores (mla_moe; the other experts' part of a layer is another
+    #: chip's)
+    experts_held: int = 0
+    expert_first: int = 0
     # --- SSM (mamba1) ---
     ssm_state: int = 0
     d_inner: int = 0
@@ -44,6 +60,12 @@ class ModelConfig:
     sliding_window: int = 0  # 0 = full attention
     qkv_bias: bool = False
     rope_theta: float = 10000.0
+    # --- latent attention (mla_moe, DeepSeek-V2/V3): a cached latent of
+    # kv_lora_rank plus one rotary key of qk_rope_head_dim per position
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
     # --- encoder-decoder (whisper) ---
     enc_layers: int = 0
     enc_seq: int = 0  # audio frames after the (stubbed) conv frontend
@@ -96,6 +118,17 @@ class ModelConfig:
             dtr = self.dt_rank or max(1, d // 16)
             per_ssm = d * 2 * di + di * self.conv_width \
                 + di * (dtr + 2 * n) + dtr * di + di * n + di * d
+        if self.family == "mla_moe":
+            r, rope = self.kv_lora_rank, self.qk_rope_head_dim
+            nope, dv, H = self.qk_nope_head_dim, self.v_head_dim, self.n_heads
+            per_attn = d * H * (nope + rope) + d * (r + rope) \
+                + r * H * (nope + dv) + H * dv * d
+            experts = self.experts_held or self.n_experts
+            per_moe = d * self.n_experts + (experts + self.n_shared_experts) \
+                * 3 * d * f
+            k = self.first_k_dense
+            return (self.n_layers * per_attn + k * 3 * d * self.dense_d_ff
+                    + (self.n_layers - k) * per_moe + 2 * v * d)
         total = 0
         for i in range(self.n_layers):
             if self.family == "dense" or self.family == "encdec":
@@ -116,6 +149,11 @@ class ModelConfig:
 
     def n_active_params(self) -> int:
         """Active parameters per token (MoE uses top_k of n_experts)."""
+        if self.family == "mla_moe":
+            held = self.experts_held or self.n_experts
+            dead = (self.n_layers - self.first_k_dense) * 3 * self.d_model \
+                * self.d_ff * (held - self.top_k * held / self.n_experts)
+            return int(self.n_params() - dead)
         if self.family != "moe":
             return self.n_params()
         d, f = self.d_model, self.d_ff

@@ -4,8 +4,8 @@ from __future__ import annotations
 
 from . import (
     falcon_mamba_7b, granite_moe_1b, hymba_1_5b, internlm2_20b,
-    llama_3_2_vision_90b, minitron_4b, mixtral_8x7b, phi3_mini_3_8b,
-    qwen2_5_32b, whisper_medium,
+    llama_3_2_vision_90b, minitron_4b, mixtral_8x7b, moonlight_16b_a3b,
+    phi3_mini_3_8b, qwen2_5_32b, whisper_medium,
 )
 from .base import SHAPES, ModelConfig, ShapeConfig, input_specs, shape_applicable
 
@@ -20,6 +20,7 @@ _MODULES = {
     "qwen2.5-32b": qwen2_5_32b,
     "minitron-4b": minitron_4b,
     "llama-3.2-vision-90b": llama_3_2_vision_90b,
+    "moonlight-16b-a3b": moonlight_16b_a3b,
 }
 
 ARCH_IDS = tuple(_MODULES)
@@ -31,7 +32,7 @@ def get_config(arch: str, smoke: bool = False) -> ModelConfig:
 
 
 def all_cells():
-    """Every (arch × shape) cell with applicability flags — 40 total."""
+    """Every (arch × shape) cell with applicability flags."""
     out = []
     for arch in ARCH_IDS:
         cfg = get_config(arch)

@@ -177,8 +177,9 @@ def _role_spec(path: str, shape: tuple, cfg, dp_shard: bool,
         return pad((M, fa), nd)       # (V, D)
     if "lm_head" in path:
         return pad((fa, M), nd)       # (D, V)
-    # --- MoE experts ---
-    if "/moe/" in path or path.startswith("moe/"):
+    # --- MoE experts (shared experts are a dense MLP, below) ---
+    if ("/moe/" in path or path.startswith("moe/")) \
+            and "/shared/" not in path:
         if "router" in path:
             return pad((fa, None), nd)
         # A dedicated expert axis (repro.ep all-to-all dispatch) wins:

@@ -3,7 +3,9 @@
 
 Families: dense / moe / ssm (mamba-only, no FFN) / hybrid (parallel
 attn+mamba heads, Hymba-style) / encdec (whisper) / vlm (periodic
-cross-attention, Llama-3.2-Vision-style).
+cross-attention, Llama-3.2-Vision-style) / mla_moe (latent attention;
+leading ``mla_dense`` layers, then ``mla_moe`` layers whose expert
+layer holds a share of the experts, DeepSeek-V3-style).
 """
 
 from __future__ import annotations
@@ -24,10 +26,30 @@ from . import ssm as S
 # Single-layer shapes / init / apply per family
 # ---------------------------------------------------------------------------
 
+#: layer kinds with latent attention over a latent cache
+MLA_KINDS = ("mla_dense", "mla_moe")
+#: layer kinds whose cache is position-indexed, so a prompt span can be
+#: written in one launch (``layer_prefill_apply``)
+SPAN_PREFILL_KINDS = ("dense", "moe") + MLA_KINDS
+
+
+def _mla_layer_shapes(cfg, dtype, kind: str) -> dict:
+    d, nk = cfg.d_model, cfg.norm
+    out = {"ln1": L.norm_shapes(d, nk, dtype),
+           "attn": L.mla_shapes(cfg, dtype),
+           "ln2": L.norm_shapes(d, nk, dtype)}
+    if kind == "mla_moe":
+        out["moe"] = M.held_moe_shapes(cfg, dtype)
+    else:
+        out["mlp"] = L.mlp_shapes(d, cfg.dense_d_ff, cfg.act, dtype)
+    return out
+
 
 def layer_shapes(cfg, dtype, kind: str) -> dict:
     d = cfg.d_model
     nk = cfg.norm
+    if kind in MLA_KINDS:
+        return _mla_layer_shapes(cfg, dtype, kind)
     out = {"ln1": L.norm_shapes(d, nk, dtype)}
     if kind in ("dense", "moe", "hybrid", "enc", "dec", "cross"):
         out["attn"] = L.attn_shapes(cfg, dtype)
@@ -53,6 +75,16 @@ def layer_shapes(cfg, dtype, kind: str) -> dict:
 def layer_init(key, cfg, dtype, kind: str) -> dict:
     ks = iter(jax.random.split(key, 8))
     d, nk = cfg.d_model, cfg.norm
+    if kind in MLA_KINDS:
+        out = {"ln1": L.norm_init(None, d, nk, dtype),
+               "attn": L.mla_init(next(ks), cfg, dtype),
+               "ln2": L.norm_init(None, d, nk, dtype)}
+        if kind == "mla_moe":
+            out["moe"] = M.held_moe_init(next(ks), cfg, dtype)
+        else:
+            out["mlp"] = L.mlp_init(next(ks), d, cfg.dense_d_ff, cfg.act,
+                                    dtype)
+        return out
     out = {"ln1": L.norm_init(next(ks), d, nk, dtype)}
     if kind in ("dense", "moe", "hybrid", "enc", "dec", "cross"):
         out["attn"] = L.attn_init(next(ks), cfg, dtype)
@@ -78,6 +110,12 @@ def layer_apply(p: dict, cfg, x: jnp.ndarray, kind: str, *,
                 q_chunk: int = 1024, k_chunk: int = 1024,
                 ssm_chunk: int = 256) -> jnp.ndarray:
     """One block forward (training/prefill path)."""
+    if kind in MLA_KINDS:
+        h = L.norm_apply(p["ln1"], x, cfg.norm)
+        x = x + shard_act(L.mla_apply(p["attn"], cfg, h, schedule=schedule,
+                                      q_chunk=q_chunk, k_chunk=k_chunk))
+        f, _ = _ffn(p, cfg, L.norm_apply(p["ln2"], x, cfg.norm), kind)
+        return x + shard_act(f)
     if kind == "ssm":
         h = L.norm_apply(p["ln1"], x, cfg.norm)
         return x + shard_act(S.ssm_apply(p["ssm"], cfg, h, chunk=ssm_chunk))
@@ -100,11 +138,19 @@ def layer_apply(p: dict, cfg, x: jnp.ndarray, kind: str, *,
             L.attn_apply(p["cross"], cfg, h, kv_src=ctx, causal=False,
                          schedule=schedule, q_chunk=q_chunk, k_chunk=k_chunk))
     h = L.norm_apply(p["ln2"], x, cfg.norm)
-    if kind == "moe":
-        f = M.moe_apply(p["moe"], cfg, h)
-    else:
-        f = L.mlp_apply(p["mlp"], h, cfg.act)
+    f, _ = _ffn(p, cfg, h, kind)
     return x + shard_act(f)
+
+
+def _ffn(p: dict, cfg, h: jnp.ndarray, kind: str) -> tuple:
+    """The block's feed-forward part: ``(out, stats)``, where ``stats``
+    holds the held-expert layer's routing counts (``moe.held_experts``)
+    and is empty for every other kind."""
+    if kind == "mla_moe":
+        return M.held_moe_apply(p["moe"], cfg, h)
+    if kind == "moe":
+        return M.moe_apply(p["moe"], cfg, h), {}
+    return L.mlp_apply(p["mlp"], h, cfg.act), {}
 
 
 # ---------------------------------------------------------------------------
@@ -116,9 +162,14 @@ def layer_cache_shapes(cfg, kind: str, B: int, cache_len: int, dtype,
                        lane: int) -> dict:
     """One layer's decode cache.  Self-attention K/V are heads-major
     ``(B, KV, T, hp)``, the head dim padded up to a multiple of ``lane``
-    (see ``model.cache_shapes``)."""
+    (see ``model.cache_shapes``); latent attention keeps one entry per
+    position, ``latent`` ``(B, 1, T, ep)``, padded the same way."""
     out = {}
     h, KV = cfg.head_dim, cfg.n_kv_heads
+    if kind in MLA_KINDS:
+        # one entry shared by every head per position (latent attention)
+        e = -(-L.mla_entry_width(cfg) // lane) * lane
+        out["latent"] = jax.ShapeDtypeStruct((B, 1, cache_len, e), dtype)
     if kind in ("dense", "moe", "hybrid", "dec", "cross"):
         T = min(cache_len, cfg.sliding_window) if cfg.sliding_window > 0 \
             else cache_len
@@ -146,9 +197,10 @@ def layer_decode_apply(p: dict, cfg, x: jnp.ndarray, cache: dict,
                        ctx_kv: Optional[dict] = None):
     """One block, one token, on layer ``at`` of the stacked cache ``cache``
     (``at`` = the layer's leading indices; ``()`` when ``cache`` is one
-    layer).  Returns ``(x, new_cache)``, the whole stack: attention
-    writes its token's K/V into the stack at ``[*at, row, :, position]``
-    and reads the layer from there.
+    layer).  Returns ``(x, new_cache, stats)``: ``new_cache`` is the
+    whole stack, into which attention writes its token's K/V at
+    ``[*at, row, :, position]`` and from which it reads the layer;
+    ``stats`` are as :func:`_ffn` gives them.
 
     For windowed caches the write index wraps (ring buffer) and the
     attention window covers the whole buffer.
@@ -163,9 +215,12 @@ def layer_decode_apply(p: dict, cfg, x: jnp.ndarray, cache: dict,
         h = L.norm_apply(p["ln1"], x, cfg.norm)
         y, sc = _ssm_decode(p["ssm"], cfg, h, cache, at)
         new_cache.update(sc)
-        return x + y, new_cache
+        return x + y, new_cache, {}
     h = L.norm_apply(p["ln1"], x, cfg.norm)
-    if kind == "cross":
+    if kind in MLA_KINDS:
+        a, new_cache["latent"] = L.mla_decode_apply(
+            p["attn"], cfg, h, cache["latent"], cache_index, at)
+    elif kind == "cross":
         a = L.cross_decode_apply(p["attn"], cfg, h, ctx_kv)
     else:
         T = cache["k"].shape[len(at) + 2]
@@ -199,38 +254,33 @@ def layer_decode_apply(p: dict, cfg, x: jnp.ndarray, cache: dict,
     if kind == "dec":
         h = L.norm_apply(p["lnx"], x, cfg.norm)
         x = x + L.cross_decode_apply(p["cross"], cfg, h, ctx_kv)
-    h = L.norm_apply(p["ln2"], x, cfg.norm)
-    if kind == "moe":
-        f = M.moe_apply(p["moe"], cfg, h)
-    else:
-        f = L.mlp_apply(p["mlp"], h, cfg.act)
-    return x + f, new_cache
+    f, stats = _ffn(p, cfg, L.norm_apply(p["ln2"], x, cfg.norm), kind)
+    return x + f, new_cache, stats
 
 
 def layer_prefill_apply(p: dict, cfg, x: jnp.ndarray, cache: dict,
                         cache_index, count, kind: str, *, at: tuple):
     """One block over a ``(B, C)`` token span (chunked prefill), on layer
     ``at`` of the stacked cache as in :func:`layer_decode_apply`.
-    Returns ``(x, new_cache)``.
+    Returns ``(x, new_cache, stats)``.
 
     Only full-cache attention families are supported: recurrent state
     (ssm/hybrid) is not position-indexed, and ring-buffer
     (sliding-window) caches would need modular span writes.  The
     batcher rejects those configs at ``submit()``.
     """
-    if kind not in ("dense", "moe"):
+    if kind not in SPAN_PREFILL_KINDS:
         raise NotImplementedError(
-            f"span prefill is only defined for dense/moe blocks, "
-            f"not kind={kind!r}")
+            f"span prefill is only defined for {SPAN_PREFILL_KINDS} "
+            f"blocks, not kind={kind!r}")
     new_cache = dict(cache)
     h = L.norm_apply(p["ln1"], x, cfg.norm)
-    a, kc, vc = L.attn_prefill_apply(p["attn"], cfg, h, cache,
-                                     cache_index, count, at)
-    new_cache["k"], new_cache["v"] = kc, vc
-    x = x + a
-    h = L.norm_apply(p["ln2"], x, cfg.norm)
-    if kind == "moe":
-        f = M.moe_apply(p["moe"], cfg, h)
+    if kind in MLA_KINDS:
+        a, new_cache["latent"] = L.mla_prefill_apply(
+            p["attn"], cfg, h, cache["latent"], cache_index, count, at)
     else:
-        f = L.mlp_apply(p["mlp"], h, cfg.act)
-    return x + f, new_cache
+        a, new_cache["k"], new_cache["v"] = L.attn_prefill_apply(
+            p["attn"], cfg, h, cache, cache_index, count, at)
+    x = x + a
+    f, stats = _ffn(p, cfg, L.norm_apply(p["ln2"], x, cfg.norm), kind)
+    return x + f, new_cache, stats

@@ -166,13 +166,12 @@ NEG_INF = -1e30
 
 
 def _attn_chunk(q, k, v, qpos, kpos, *, causal: bool, window: int,
-                kv_valid: int = 0):
+                kv_valid: int = 0, scale: float):
     """One (q-chunk × kv-chunk) block of online softmax.
 
-    q: (B, qc, KV, G, dh); k/v: (B, kc, KV, dh).
+    q: (B, qc, KV, G, dh); k/v: (B, kc, KV, dv).
     Returns (scores_max, exp_sum, acc) contributions in fp32.
     """
-    scale = q.shape[-1] ** -0.5
     s = jnp.einsum("bqkgd,bckd->bqkgc", q, k,
                    preferred_element_type=jnp.float32) * scale
     mask = jnp.ones((q.shape[1], k.shape[1]), dtype=bool)
@@ -213,15 +212,19 @@ def chunked_attention(
     k_chunk: int = 1024,
     schedule: str = "masked",   # masked | tri
     q_offset: int = 0,          # absolute position of q[0] (cross/cache)
+    scale: Optional[float] = None,  # default dh ** -0.5
 ) -> jnp.ndarray:
     """Memory-efficient multi-head attention with GQA.
 
     ``schedule='tri'`` unrolls only the lower-triangular (or in-window)
     chunk pairs — the load-balanced schedule (exact FLOPs); ``masked``
     visits every pair with masking (compact HLO, 2× causal FLOP waste).
+    Values may be narrower than queries and keys (``v`` (B, T, KV, dv));
+    the output then has ``dv``.
     """
     B, S, H, dh = q.shape
-    T, KV = k.shape[1], k.shape[2]
+    T, KV, dv = k.shape[1], k.shape[2], v.shape[3]
+    scale = dh ** -0.5 if scale is None else scale
     G = H // KV
     # Ragged lengths (whisper's 1500 frames, vision's 1601 patches): pad to
     # the chunk grid; padded KV is masked via kv_valid, padded q rows are
@@ -246,7 +249,7 @@ def chunked_attention(
 
     qs = q.reshape(B, nq, q_chunk, KV, G, dh)
     ks = k.reshape(B, nk, k_chunk, KV, dh)
-    vs = v.reshape(B, nk, k_chunk, KV, dh)
+    vs = v.reshape(B, nk, k_chunk, KV, dv)
 
     # banded window scan needs q/k chunk grids in lockstep
     kv_src_aligned = (q_chunk == k_chunk) and q_offset == 0
@@ -268,7 +271,7 @@ def chunked_attention(
         if schedule == "tri":
             m = jnp.full((B, q_chunk, KV, G), NEG_INF, jnp.float32)
             l = jnp.zeros((B, q_chunk, KV, G), jnp.float32)
-            acc = jnp.zeros((B, q_chunk, KV, G, dh), jnp.float32)
+            acc = jnp.zeros((B, q_chunk, KV, G, dv), jnp.float32)
             carry = (m, l, acc)
             for j in range(nk):
                 if not kv_visible(j):
@@ -276,7 +279,7 @@ def chunked_attention(
                 kpos = j * k_chunk + jnp.arange(k_chunk)
                 part = _attn_chunk(qi, ks[:, j], vs[:, j], qpos, kpos,
                                    causal=causal, window=window,
-                                   kv_valid=kv_valid)
+                                   kv_valid=kv_valid, scale=scale)
                 carry = _merge_online(carry, part)
             m, l, acc = carry
         elif window > 0 and causal and kv_src_aligned:
@@ -294,7 +297,8 @@ def chunked_attention(
                 vj = jax.lax.dynamic_index_in_dim(vs, j, 1, keepdims=False)
                 kpos = j * k_chunk + jnp.arange(k_chunk)
                 part = _attn_chunk(qi, kj, vj, qpos, kpos, causal=causal,
-                                   window=window, kv_valid=kv_valid)
+                                   window=window, kv_valid=kv_valid,
+                                   scale=scale)
                 valid = (j_raw >= 0).astype(jnp.float32)
                 part = (jnp.where(valid > 0, part[0], NEG_INF),
                         part[1] * valid, part[2] * valid)
@@ -303,7 +307,7 @@ def chunked_attention(
             init = (
                 jnp.full((B, q_chunk, KV, G), NEG_INF, jnp.float32),
                 jnp.zeros((B, q_chunk, KV, G), jnp.float32),
-                jnp.zeros((B, q_chunk, KV, G, dh), jnp.float32),
+                jnp.zeros((B, q_chunk, KV, G, dv), jnp.float32),
             )
             (m, l, acc), _ = jax.lax.scan(body, init, jnp.arange(noff))
         else:
@@ -313,13 +317,13 @@ def chunked_attention(
                 kpos = j * k_chunk + jnp.arange(k_chunk)
                 part = _attn_chunk(qi, kj, vj, qpos, kpos,
                                    causal=causal, window=window,
-                                   kv_valid=kv_valid)
+                                   kv_valid=kv_valid, scale=scale)
                 return _merge_online(carry, part), None
 
             init = (
                 jnp.full((B, q_chunk, KV, G), NEG_INF, jnp.float32),
                 jnp.zeros((B, q_chunk, KV, G), jnp.float32),
-                jnp.zeros((B, q_chunk, KV, G, dh), jnp.float32),
+                jnp.zeros((B, q_chunk, KV, G, dv), jnp.float32),
             )
             (m, l, acc), _ = jax.lax.scan(body, init, jnp.arange(nk))
         out = acc / jnp.maximum(l, 1e-30)[..., None]
@@ -327,7 +331,7 @@ def chunked_attention(
 
     if nq == 1:
         out = q_block(0, qs[:, 0])
-        return out.reshape(B, S, H, dh)[:, :S0]
+        return out.reshape(B, S, H, dv)[:, :S0]
     # Unrolled python loop over q chunks in 'tri' (each body differs);
     # scan in 'masked'.
     if schedule == "tri":
@@ -339,8 +343,8 @@ def chunked_attention(
                 qs, i, 1, keepdims=False))
 
         _, out = jax.lax.scan(qbody, None, jnp.arange(nq))
-        out = jnp.moveaxis(out, 0, 1)  # (B, nq, qc, KV, G, dh)
-    return out.reshape(B, S, H, dh)[:, :S0]
+        out = jnp.moveaxis(out, 0, 1)  # (B, nq, qc, KV, G, dv)
+    return out.reshape(B, S, H, dv)[:, :S0]
 
 
 def pad_head(x: jnp.ndarray, width: int) -> jnp.ndarray:
@@ -358,6 +362,7 @@ def decode_attention(
     cache_index: jnp.ndarray,  # () or (B,) int32 — valid cache entries
     *,
     window: int = 0,
+    scale: Optional[float] = None,  # default dh ** -0.5
 ) -> jnp.ndarray:
     """One-token attention against a (possibly windowed) KV cache.
 
@@ -376,7 +381,8 @@ def decode_attention(
     G = H // KV
     qg = pad_head(q, dc).reshape(B, KV, G, dc)
     s = jnp.einsum("bkgd,bktd->bkgt", qg, k_cache,
-                   preferred_element_type=jnp.float32) * dh ** -0.5
+                   preferred_element_type=jnp.float32) * (
+        dh ** -0.5 if scale is None else scale)
     pos = jnp.arange(T)
     ci = jnp.asarray(cache_index)
     if ci.ndim == 0:
@@ -577,6 +583,7 @@ def prefill_attention(
     k_cache: jnp.ndarray,  # (B, KV, T, dc), dc >= dh
     v_cache: jnp.ndarray,
     cache_index: jnp.ndarray,  # (B,) absolute position of q[:, 0]
+    scale: Optional[float] = None,  # default dh ** -0.5
 ) -> jnp.ndarray:
     """Causal attention of a C-token span against the full KV cache.
 
@@ -600,7 +607,8 @@ def prefill_attention(
     # heads-major like the cache: (b, k) lead both operands of both dots
     qg = pad_head(q, dc).reshape(B, C, KV, G, dc).transpose(0, 2, 3, 1, 4)
     s = jnp.einsum("bkgcd,bktd->bkgct", qg, k_cache,
-                   preferred_element_type=jnp.float32) * dh ** -0.5
+                   preferred_element_type=jnp.float32) * (
+        dh ** -0.5 if scale is None else scale)
     ci = jnp.asarray(cache_index, jnp.int32)
     qpos = ci[:, None] + jnp.arange(C, dtype=jnp.int32)[None, :]   # (B, C)
     mask = jnp.arange(T)[None, None, :] <= qpos[..., None]         # (B, C, T)
@@ -640,6 +648,136 @@ def attn_prefill_apply(p: dict, cfg, x: jnp.ndarray, cache: dict,
                             ci)
     y = dense_apply(p["wo"], out.reshape(B, C, H * h))
     return y, k_stack, v_stack
+
+
+# ---------------------------------------------------------------------------
+# Latent attention (DeepSeek-V2/V3 MLA) over a latent cache
+# ---------------------------------------------------------------------------
+#
+# Every position caches one entry shared by all heads: the normalised
+# latent ``c`` (kv_lora_rank) and the rotated key ``k_rope``
+# (qk_rope_head_dim), ``[c, k_rope]``.  Head h's key is
+# ``[c @ Wk_h, k_rope]`` and its value ``c @ Wv_h``, with ``Wk_h`` and
+# ``Wv_h`` the halves of ``wkv_b``.  The attention is computed in the
+# absorbed form: ``Wk_h`` is folded into the query
+# (``q_lat = Wk_h @ q_nope``), so each head scores ``[q_lat, q_rope]``
+# against the entries themselves, and ``Wv_h`` is applied to the
+# softmax-weighted sum of the latents.  That is attention with one
+# shared key/value head (``KV = 1``), keys of ``rank + rope`` and values
+# the entry's first ``rank``: the cache is ``(B, 1, T, e)`` and the
+# in-place writes and the attention of the other families serve it.
+
+
+def mla_shapes(cfg, dtype) -> dict:
+    d, H, r = cfg.d_model, cfg.n_heads, cfg.kv_lora_rank
+    nope, rope, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    return {"wq": dense_shapes(d, H * (nope + rope), False, dtype),
+            "wkv_a": dense_shapes(d, r + rope, False, dtype),
+            "kv_norm": norm_shapes(r, "rmsnorm", dtype),
+            "wkv_b": dense_shapes(r, H * (nope + dv), False, dtype),
+            "wo": dense_shapes(H * dv, d, False, dtype)}
+
+
+def mla_init(key, cfg, dtype) -> dict:
+    shapes = mla_shapes(cfg, dtype)
+    keys = iter(jax.random.split(key, 4))
+    return {k: (norm_init(None, v["scale"].shape[0], "rmsnorm", dtype)
+                if k == "kv_norm" else
+                dense_init(next(keys), *v["w"].shape, False, dtype))
+            for k, v in shapes.items()}
+
+
+def mla_entry_width(cfg) -> int:
+    return cfg.kv_lora_rank + cfg.qk_rope_head_dim
+
+
+def mla_scale(cfg) -> float:
+    """The softmax scale: one over the root of the query-key head size."""
+    return (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5
+
+
+def _wkv_b(p: dict, cfg):
+    """``wkv_b`` as ``(rank, H, nope + dv)``: the key and value halves."""
+    return p["wkv_b"]["w"].reshape(cfg.kv_lora_rank, cfg.n_heads, -1)
+
+
+def mla_project(p: dict, cfg, x: jnp.ndarray, pos: jnp.ndarray):
+    """Queries and cache entries of a ``(B, S, d)`` span at positions
+    ``pos`` ``(B, S)``: absorbed queries ``(B, S, H, rank + rope)`` and
+    entries ``(B, S, 1, rank + rope)``."""
+    B, S, _ = x.shape
+    H, r = cfg.n_heads, cfg.kv_lora_rank
+    nope, rope = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
+    q = dense_apply(p["wq"], x).reshape(B, S, H, nope + rope)
+    kv = dense_apply(p["wkv_a"], x)
+    c = norm_apply(p["kv_norm"], kv[..., :r], "rmsnorm")
+    k_rope = apply_rope(kv[..., r:].reshape(B, S, 1, rope), pos,
+                        cfg.rope_theta)
+    q_rope = apply_rope(q[..., nope:], pos, cfg.rope_theta)
+    q_lat = jnp.einsum("bshn,rhn->bshr", q[..., :nope],
+                       _wkv_b(p, cfg)[..., :nope],
+                       preferred_element_type=jnp.float32).astype(x.dtype)
+    return (jnp.concatenate([q_lat, q_rope], axis=-1),
+            jnp.concatenate([c[:, :, None, :], k_rope], axis=-1))
+
+
+def mla_output(p: dict, cfg, o_lat: jnp.ndarray) -> jnp.ndarray:
+    """Each head's value projection of its weighted latent sum
+    ``o_lat[..., :rank]`` ``(B, S, H, >= rank)``, then the output
+    projection."""
+    B, S = o_lat.shape[:2]
+    nope, r = cfg.qk_nope_head_dim, cfg.kv_lora_rank
+    o = jnp.einsum("bshr,rhv->bshv", o_lat[..., :r],
+                   _wkv_b(p, cfg)[..., nope:],
+                   preferred_element_type=jnp.float32).astype(o_lat.dtype)
+    return dense_apply(p["wo"], o.reshape(B, S, -1))
+
+
+def mla_apply(p: dict, cfg, x: jnp.ndarray, *, schedule: str = "masked",
+              q_chunk: int = 1024, k_chunk: int = 1024) -> jnp.ndarray:
+    """Causal latent attention over a whole ``(B, S, d)`` sequence
+    (training / forward)."""
+    with jax.named_scope("mla_attention"):
+        B, S, _ = x.shape
+        q, e = mla_project(p, cfg, x, jnp.arange(S)[None, :])
+        o = chunked_attention(q, e, e[..., :cfg.kv_lora_rank], causal=True,
+                              q_chunk=q_chunk, k_chunk=k_chunk,
+                              schedule=schedule, scale=mla_scale(cfg))
+        return mla_output(p, cfg, o)
+
+
+def mla_decode_apply(p: dict, cfg, x: jnp.ndarray, latent: jnp.ndarray,
+                     cache_index, at: tuple) -> tuple:
+    """One token per row: write its entry into the stacked latent cache
+    at ``[*at, row, 0, cache_index[row]]`` and attend over the layer's
+    valid entries.  Returns ``(out, latent)``."""
+    with jax.named_scope("mla_attention"):
+        B = x.shape[0]
+        ci = jnp.asarray(cache_index, jnp.int32)
+        q, e = mla_project(p, cfg, x, decode_positions(ci, B))
+        latent = kv_cache_update(latent, e, ci, at)
+        c = layer_of(latent, at)
+        T = c.shape[2]
+        o = decode_attention(q, c, c, jnp.minimum(ci + 1, T),
+                             scale=mla_scale(cfg))
+        return mla_output(p, cfg, o), latent
+
+
+def mla_prefill_apply(p: dict, cfg, x: jnp.ndarray, latent: jnp.ndarray,
+                      cache_index, count, at: tuple) -> tuple:
+    """Span prefill: write each row's first ``count`` entries at
+    ``cache_index + lane`` and attend causally over the layer's cache,
+    as :func:`attn_prefill_apply` does.  Returns ``(out, latent)``."""
+    with jax.named_scope("mla_attention"):
+        B, C, _ = x.shape
+        ci = jnp.broadcast_to(jnp.asarray(cache_index, jnp.int32), (B,))
+        pos = ci[:, None] + jnp.arange(C, dtype=jnp.int32)[None, :]
+        q, e = mla_project(p, cfg, x, pos)
+        latent = kv_cache_update_span(latent, e, ci,
+                                      jnp.asarray(count, jnp.int32), at)
+        c = layer_of(latent, at)
+        o = prefill_attention(q, c, c, ci, scale=mla_scale(cfg))
+        return mla_output(p, cfg, o), latent
 
 
 def cross_decode_apply(p: dict, cfg, x: jnp.ndarray, cross_kv: dict):

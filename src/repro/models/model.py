@@ -9,6 +9,7 @@ into homogeneous stacks:
 * encdec  — encoder stack (bidir) + decoder stack (causal + cross)
 * vlm     — groups of (cross_every-1) self layers + 1 cross layer,
             outer scan over groups, inner scan over self layers
+* mla_moe — the leading dense layers, then the expert layers
 * others  — one stack
 
 The dry-run never materialises params: ``param_shapes()`` returns a
@@ -27,6 +28,11 @@ from ..configs.base import ModelConfig
 from ..distributed.sharding import act_spec, batch_spec, shard, shard_act, shard_logits
 from . import blocks as B
 from . import layers as L
+from . import moe as M
+
+
+#: families whose caches take a prompt span in one launch (prefill_step)
+SPAN_PREFILL_FAMILIES = ("dense", "moe", "mla_moe")
 
 
 def _dtype(cfg) -> jnp.dtype:
@@ -53,6 +59,9 @@ def _plan(cfg: ModelConfig):
         return [("layers", "ssm", cfg.n_layers, 0)]
     if cfg.family == "hybrid":
         return [("layers", "hybrid", cfg.n_layers, 0)]
+    if cfg.family == "mla_moe":
+        return [("dense_layers", "mla_dense", cfg.first_k_dense, 0),
+                ("layers", "mla_moe", cfg.n_layers - cfg.first_k_dense, 0)]
     if cfg.family == "encdec":
         return [("enc_layers", "enc", cfg.enc_layers, 0),
                 ("dec_layers", "dec", cfg.n_layers, 0)]
@@ -183,11 +192,11 @@ def forward(params: dict, cfg: ModelConfig, batch: dict, *,
             lambda h, p: B.layer_apply(p, cfg, h, "dec", ctx=ctx, **kw),
             remat=remat)
     else:
-        kind = _plan(cfg)[0][1]
-        x = _scan_stack(
-            params["layers"], x,
-            lambda h, p: B.layer_apply(p, cfg, h, kind, **kw),
-            remat=remat)
+        for name, kind, _, _ in _plan(cfg):
+            x = _scan_stack(
+                params[name], x,
+                lambda h, p, kind=kind: B.layer_apply(p, cfg, h, kind, **kw),
+                remat=remat)
 
     if last_only:
         x = x[:, -1:]
@@ -287,8 +296,10 @@ def _decode_scan(stack_params, cache_stack, x, step_fn, outer: tuple = ()):
 
     ``step_fn(x, layer_params, cache_stack, at)`` runs layer ``at`` =
     ``outer + (l,)`` (its leading indices in the stack) and returns
-    ``(x, cache_stack)``.  Attention K/V are written straight into the
-    carried stack at ``[*at, rows, positions]`` and read from it there:
+    ``(x, cache_stack, stats)``; the scan returns the same, each of
+    ``stats`` stacked over the layers.  Attention K/V are written
+    straight into the carried stack at ``[*at, rows, positions]`` and
+    read from it there:
     no per-layer slice is built or written back, so a launch that
     donates the cache writes only the positions it produces, in place.
     Recurrent state (ssm/hybrid) is small and keeps a per-layer slice
@@ -299,20 +310,38 @@ def _decode_scan(stack_params, cache_stack, x, step_fn, outer: tuple = ()):
 
     def body(carry, l):
         x, cache = carry
-        x, cache = step_fn(x, _idx(stack_params, l), cache, outer + (l,))
-        return (x, cache), None
+        x, cache, stats = step_fn(x, _idx(stack_params, l), cache,
+                                  outer + (l,))
+        return (x, cache), stats
 
-    (x, cache_stack), _ = jax.lax.scan(
+    (x, cache_stack), stats = jax.lax.scan(
         body, (x, cache_stack), jnp.arange(n))
-    return x, cache_stack
+    return x, cache_stack, stats
+
+
+def _stacks(params, cache, cfg, x, step_fn) -> tuple:
+    """Run every stack of the plan in order through :func:`_decode_scan`
+    (``step_fn(x, layer_params, cache_stack, at, kind)``).  Returns
+    ``(x, new_cache, stats)``: the expert layers' routing counts reduced
+    over the layers (``moe.reduce_stats``), or ``{}``."""
+    new_cache, stats = dict(cache), {}
+    for name, kind, _, _ in _plan(cfg):
+        x, new_cache[name], st = _decode_scan(
+            params[name], cache[name], x,
+            lambda xx, lp, c, at, kind=kind: step_fn(xx, lp, c, at, kind))
+        stats.update(st)
+    return x, new_cache, M.reduce_stats(stats)
 
 
 def decode_step(params: dict, cfg: ModelConfig, cache: dict,
-                batch: dict) -> tuple:
+                batch: dict, with_stats: bool = False) -> tuple:
     """One token for every sequence in the batch against the cache.
 
     batch = {"tokens": (B, 1), "cache_index": () or (B,)} — returns
-    (logits (B, vocab), new_cache).  A per-row cache index lets the
+    (logits (B, vocab), new_cache), and with ``with_stats`` also the
+    launch's routing counts (``moe.held_experts``, summed over the layers
+    with ``expert_max`` the largest; ``{}`` for a family without the
+    held-expert layer).  A per-row cache index lets the
     continuous batcher keep each decode slot at its own position.
 
     Each layer writes one K/V position per row into the stacked cache
@@ -323,7 +352,7 @@ def decode_step(params: dict, cfg: ModelConfig, cache: dict,
     tokens, cache_index = batch["tokens"], batch["cache_index"]
     x = jnp.take(params["embed"], tokens, axis=0)
     x = shard_act(x)
-    new_cache = dict(cache)
+    new_cache, stats = dict(cache), {}
 
     if cfg.family == "vlm":
         gp_tree = {"self": params["self_layers"],
@@ -337,9 +366,9 @@ def decode_step(params: dict, cfg: ModelConfig, cache: dict,
         def gbody(carry, gi):
             x, self_cache = carry
             gp = _idx(gp_tree, gi)
-            x, self_cache = _decode_scan(gp["self"], self_cache, x,
-                                         self_step, outer=(gi,))
-            x, _ = B.layer_decode_apply(
+            x, self_cache, _ = _decode_scan(gp["self"], self_cache, x,
+                                            self_step, outer=(gi,))
+            x, _, _ = B.layer_decode_apply(
                 gp["cross"], cfg, x, {}, cache_index, "cross",
                 ctx_kv=_idx(cache["cross_kv"], gi))
             return (x, self_cache), None
@@ -353,28 +382,24 @@ def decode_step(params: dict, cfg: ModelConfig, cache: dict,
                 lp, cfg, xx, c, cache_index, "dec", at=at,
                 ctx_kv=_idx(cache["cross_kv"], at[0]))
 
-        x, new_dec = _decode_scan(params["dec_layers"],
-                                  cache["dec_layers"], x, dec_step)
+        x, new_dec, _ = _decode_scan(params["dec_layers"],
+                                     cache["dec_layers"], x, dec_step)
         new_cache["dec_layers"] = new_dec
     else:
-        kind = _plan(cfg)[0][1]
-
-        def lyr_step(xx, lp, c, at):
+        def lyr_step(xx, lp, c, at, kind):
             return B.layer_decode_apply(lp, cfg, xx, c, cache_index, kind,
                                         at=at)
 
-        x, new_layers = _decode_scan(params["layers"], cache["layers"], x,
-                                     lyr_step)
-        new_cache["layers"] = new_layers
+        x, new_cache, stats = _stacks(params, cache, cfg, x, lyr_step)
 
     x = L.norm_apply(params["final_norm"], x, cfg.norm)
     head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-    logits = (x @ head)[:, 0]
-    return shard_logits(logits), new_cache
+    logits = shard_logits((x @ head)[:, 0])
+    return (logits, new_cache, stats) if with_stats else (logits, new_cache)
 
 
 def prefill_step(params: dict, cfg: ModelConfig, cache: dict,
-                 batch: dict) -> tuple:
+                 batch: dict, with_stats: bool = False) -> tuple:
     """Write a span of prompt tokens through the model at per-row cache
     indices — the chunked-prefill primitive for the continuous batcher.
 
@@ -385,7 +410,8 @@ def prefill_step(params: dict, cfg: ModelConfig, cache: dict,
     (padded lanes scatter out of bounds and are dropped), so slots deep
     in decode can share a launch buffer with prefilling neighbours.
 
-    Returns ``(logits (B, vocab), new_cache)`` where row b's logits are
+    Returns ``(logits (B, vocab), new_cache)`` (and the routing counts
+    with ``with_stats``, as :func:`decode_step`) where row b's logits are
     taken at its LAST valid lane (``count[b] - 1``) — the same shape
     contract as :func:`decode_step`, so a slot whose prefill just
     finished can seed decode from these logits.  Rows with ``count == 0``
@@ -400,14 +426,14 @@ def prefill_step(params: dict, cfg: ModelConfig, cache: dict,
     positions into the stacked cache, in place when the caller donates
     it (the caller must not reuse the array it passed).
 
-    Only full-cache attention families (dense/moe, no sliding window)
-    are supported — recurrent and ring-buffer caches have no
-    position-indexed span write.
+    Only full-cache attention families (:data:`SPAN_PREFILL_FAMILIES`,
+    no sliding window) are supported — recurrent and ring-buffer caches
+    have no position-indexed span write.
     """
-    if cfg.family not in ("dense", "moe"):
+    if cfg.family not in SPAN_PREFILL_FAMILIES:
         raise NotImplementedError(
             f"prefill_step needs a position-indexed KV cache "
-            f"(dense/moe), not family={cfg.family!r}")
+            f"{SPAN_PREFILL_FAMILIES}, not family={cfg.family!r}")
     if cfg.sliding_window > 0:
         raise NotImplementedError(
             "prefill_step writes absolute-position spans; ring-buffer "
@@ -417,19 +443,15 @@ def prefill_step(params: dict, cfg: ModelConfig, cache: dict,
     count = jnp.asarray(batch["count"], jnp.int32)
     x = jnp.take(params["embed"], tokens, axis=0)
     x = shard_act(x)
-    new_cache = dict(cache)
-    kind = _plan(cfg)[0][1]
 
-    def lyr_step(xx, lp, c, at):
+    def lyr_step(xx, lp, c, at, kind):
         return B.layer_prefill_apply(lp, cfg, xx, c, cache_index, count,
                                      kind, at=at)
 
-    x, new_layers = _decode_scan(params["layers"], cache["layers"], x,
-                                 lyr_step)
-    new_cache["layers"] = new_layers
+    x, new_cache, stats = _stacks(params, cache, cfg, x, lyr_step)
     x = L.norm_apply(params["final_norm"], x, cfg.norm)
     last = jnp.clip(count - 1, 0, tokens.shape[1] - 1)
     x = jnp.take_along_axis(x, last[:, None, None], axis=1)
     head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-    logits = (x @ head)[:, 0]
-    return shard_logits(logits), new_cache
+    logits = shard_logits((x @ head)[:, 0])
+    return (logits, new_cache, stats) if with_stats else (logits, new_cache)

@@ -34,6 +34,7 @@ import jax.numpy as jnp
 
 from ..kernels.moe_dispatch.ops import dispatch_combine
 from ..sched import ExpertCapacityProvider
+from . import layers as L
 from .layers import _norm_init
 
 
@@ -234,3 +235,114 @@ def moe_ref(p: dict, cfg, x: jnp.ndarray) -> jnp.ndarray:
     sel = jnp.take_along_axis(dense, ids[..., None], axis=1)  # (T, K, d)
     return jnp.einsum("tkd,tk->td", sel, gates.astype(x.dtype)).reshape(
         orig_shape)
+
+
+# ---------------------------------------------------------------------------
+# The expert layer that holds a share of the experts (mla_moe), dropless
+# ---------------------------------------------------------------------------
+#
+# The router scores all ``n_experts``; this layer holds
+# ``experts_held`` of them (``expert_first ..``) and computes exactly
+# the (token, choice) pairs routed to those, with nothing dropped or
+# rerouted: the pairs are sorted by expert and each held expert's rows
+# go through one grouped product (``jax.lax.ragged_dot``).  Pairs routed
+# to experts held elsewhere are left out; their part of the layer is
+# another chip's.  The shared experts are computed for every token.
+# The capacity dispatch above (LC/DLBC) stays the training path of the
+# ``moe`` family.
+
+
+def held_moe_shapes(cfg, dtype) -> dict:
+    d, f, E, Eh = cfg.d_model, cfg.d_ff, cfg.n_experts, cfg.experts_held
+    return {
+        "router": {"w": jax.ShapeDtypeStruct((d, E), jnp.float32),
+                   "bias": jax.ShapeDtypeStruct((E,), jnp.float32)},
+        "w1": jax.ShapeDtypeStruct((Eh, d, f), dtype),
+        "w3": jax.ShapeDtypeStruct((Eh, d, f), dtype),
+        "w2": jax.ShapeDtypeStruct((Eh, f, d), dtype),
+        "shared": L.mlp_shapes(d, cfg.n_shared_experts * f, "swiglu", dtype),
+    }
+
+
+def held_moe_init(key, cfg, dtype) -> dict:
+    d, f, E, Eh = cfg.d_model, cfg.d_ff, cfg.n_experts, cfg.experts_held
+    k0, k1, k2, k3, k4 = jax.random.split(key, 5)
+    return {
+        "router": {"w": _norm_init(k0, (d, E), d ** -0.5, jnp.float32),
+                   "bias": jnp.zeros((E,), jnp.float32)},
+        "w1": _norm_init(k1, (Eh, d, f), d ** -0.5, dtype),
+        "w3": _norm_init(k3, (Eh, d, f), d ** -0.5, dtype),
+        "w2": _norm_init(k2, (Eh, f, d), f ** -0.5, dtype),
+        "shared": L.mlp_init(k4, d, cfg.n_shared_experts * f, "swiglu",
+                             dtype),
+    }
+
+
+def route_sigmoid(x: jnp.ndarray, router: dict, top_k: int, scale: float):
+    """DeepSeek-V3's router over every expert: sigmoid scores (float32 at
+    full precision), the top ``top_k`` by score plus the correction
+    ``bias``, their scores normalised to sum 1 and times ``scale``.
+    x: (T, d) → (gates (T, K) float32, expert ids (T, K) int32)."""
+    scores = jax.nn.sigmoid(jnp.matmul(
+        x.astype(jnp.float32), router["w"],
+        precision=jax.lax.Precision.HIGHEST))
+    _, ids = jax.lax.top_k(scores + router["bias"], top_k)
+    gates = jnp.take_along_axis(scores, ids, axis=-1)
+    gates = gates / (jnp.sum(gates, axis=-1, keepdims=True) + 1e-20) * scale
+    return gates, ids.astype(jnp.int32)
+
+
+def held_experts(p: dict, cfg, x: jnp.ndarray, gates: jnp.ndarray,
+                 ids: jnp.ndarray):
+    """The held experts' part of the layer for tokens ``x`` (T, d) routed
+    to ``ids`` with ``gates``: every pair on a held expert, none else.
+    Returns ``(y (T, d) float32, stats)``, ``stats`` the layer's counts:
+    ``routed_here``, the pairs on held experts; ``experts_hit``, the held
+    experts with at least one; ``expert_max``, the most pairs on one.
+    The layer is dropless by construction: the group sizes count every
+    pair on a held expert, so each is a row of the grouped product."""
+    T, K = ids.shape
+    Eh = cfg.experts_held
+    local = ids - cfg.expert_first
+    here = (local >= 0) & (local < Eh)
+    group = jnp.where(here, local, Eh).reshape(-1)      # absent: group Eh
+    order = jnp.argsort(group, stable=True)
+    held = jnp.bincount(group, length=Eh + 1)[:Eh]
+    rows = jnp.take(x, order // K, axis=0)              # (T*K, d) by expert
+
+    def grouped(a, w):
+        return jax.lax.ragged_dot(a, w, held,
+                                  preferred_element_type=jnp.float32)
+
+    h = jax.nn.silu(grouped(rows, p["w1"])) * grouped(rows, p["w3"])
+    y = grouped(h.astype(x.dtype), p["w2"])
+    # back to (token, choice) order; rows past the held groups are not
+    # written by the grouped product and are masked, not read
+    y = jnp.take(y, jnp.argsort(order), axis=0).reshape(T, K, -1)
+    y = jnp.where(here[..., None], y, 0.0)
+    out = jnp.einsum("tkd,tk->td", y, jnp.where(here, gates, 0.0))
+    stats = {"routed_here": jnp.sum(held),
+             "experts_hit": jnp.sum(held > 0),
+             "expert_max": jnp.max(held)}
+    return out, stats
+
+
+def held_moe_apply(p: dict, cfg, x: jnp.ndarray):
+    """The expert layer of ``mla_moe``: shared experts plus the held
+    experts' part.  x: (B, S, d) → (y (B, S, d), stats)."""
+    shape = x.shape
+    x = x.reshape(-1, shape[-1])
+    with jax.named_scope("moe_router"):
+        gates, ids = route_sigmoid(x, p["router"], cfg.top_k,
+                                   cfg.routed_scale)
+    with jax.named_scope("moe_experts"):
+        y, stats = held_experts(p, cfg, x, gates, ids)
+        y = y + L.mlp_apply(p["shared"], x, "swiglu").astype(jnp.float32)
+    return y.astype(x.dtype).reshape(shape), stats
+
+
+def reduce_stats(stats: dict) -> dict:
+    """Per-layer counts (leading layer axis) to one launch's: sums, and
+    the largest ``expert_max``."""
+    return {k: (jnp.max(v) if k == "expert_max" else jnp.sum(v))
+            for k, v in stats.items()}

@@ -113,13 +113,25 @@ _MX_QUEUE_DEPTH = obs_metrics.gauge("serve.queue_depth")
 
 def step_programs(cfg: ModelConfig) -> tuple:
     """The jitted ``(decode, prefill)`` steps, each called as
-    ``fn(params, cache, batch) -> (logits, cache)``.  Both DONATE the
-    cache (argument 1) and write it in place: the array passed in is
-    invalid afterwards, and the caller keeps the one returned."""
-    return (jax.jit(lambda p, c, b: MDL.decode_step(p, cfg, c, b),
-                    donate_argnums=(1,)),
-            jax.jit(lambda p, c, b: MDL.prefill_step(p, cfg, c, b),
-                    donate_argnums=(1,)))
+    ``fn(params, cache, batch) -> (logits, cache, stats)``, ``stats`` the
+    launch's routing counts (``models.moe.held_experts``; ``{}`` for a
+    family without the held-expert layer).  Both DONATE the cache
+    (argument 1) and write it in place: the array passed in is invalid
+    afterwards, and the caller keeps the one returned.
+
+    Only a held-expert model asks the step for its counts: every other
+    family calls ``MDL.decode_step``/``prefill_step`` through their
+    four-argument ``(logits, cache)`` form, which the benchmark's
+    fault-injection test (``tests/chipbench/test_chipbench_rehearsal``)
+    replaces with a function of that form."""
+    def program(step):
+        if cfg.experts_held:
+            run = lambda p, c, b: step(p, cfg, c, b, with_stats=True)  # noqa: E731
+        else:
+            run = lambda p, c, b: (*step(p, cfg, c, b), {})  # noqa: E731
+        return jax.jit(run, donate_argnums=(1,))
+
+    return program(MDL.decode_step), program(MDL.prefill_step)
 
 
 @dataclass
@@ -326,6 +338,10 @@ class ContinuousBatcher:
         #: the jitted device steps this batcher launches; both donate the
         #: cache, and ``self.cache`` is rebound to each launch's result
         self.decode_fn, self.prefill_fn = step_programs(cfg)
+        #: while tracing: ``(span args, routing counts on the device)``
+        #: of each launch since the last token fetch, which brings the
+        #: counts back with the token ids (no host sync of their own)
+        self._routing: List[tuple] = []
 
     # -- admission (DLBC vs LC vs weighted-DLBC) -----------------------------
 
@@ -356,10 +372,11 @@ class ContinuousBatcher:
                 f"cannot fit cache_len={self.cache_len}")
         if len(req.prompt) > 1 and (self.cfg.sliding_window > 0
                                     or self.cfg.family not in
-                                    ("dense", "moe")):
+                                    MDL.SPAN_PREFILL_FAMILIES):
             raise NotImplementedError(
                 f"span prefill needs a full position-indexed KV cache "
-                f"(dense/moe, no sliding window); "
+                f"({'/'.join(MDL.SPAN_PREFILL_FAMILIES)}, no sliding "
+                f"window); "
                 f"family={self.cfg.family!r} "
                 f"sliding_window={self.cfg.sliding_window} is limited "
                 f"to single-token prompts")
@@ -546,15 +563,16 @@ class ContinuousBatcher:
                 # is dispatched, and on the CPU ``jnp.asarray`` may alias
                 # the host array instead of copying it
                 cache_index = self.slot_pos.copy()
-            with obs.trace_span("serve", "prefill_chunk",
-                                {"slots": len(chunk_of),
-                                 "tokens": int(sum(chunk_of.values()))}
-                                if obs.enabled() else None):
-                _, self.cache = self.prefill_fn(
+            args = ({"slots": len(chunk_of),
+                     "tokens": int(sum(chunk_of.values()))}
+                    if obs.enabled() else None)
+            with obs.trace_span("serve", "prefill_chunk", args):
+                _, self.cache, routing = self.prefill_fn(
                     self.params, self.cache,
                     {"tokens": jnp.asarray(tokens),
                      "cache_index": jnp.asarray(cache_index, jnp.int32),
                      "count": jnp.asarray(counts, jnp.int32)})
+                self._keep_routing(args, routing)
             cost += max(chunk_of.values())
             for i, c in chunk_of.items():
                 st = self._prefilling[i]
@@ -621,9 +639,8 @@ class ContinuousBatcher:
         decoding = [i for i in active if i not in self._prefilling]
         step_cost = prefill_cost + (1 if decoding else 0)
         if decoding:
-            with obs.trace_span("serve", "decode_launch",
-                                {"active": len(decoding)} if obs.enabled()
-                                else None):
+            args = {"active": len(decoding)} if obs.enabled() else None
+            with obs.trace_span("serve", "decode_launch", args):
                 tokens = np.zeros((self.n_slots, 1), np.int32)
                 for i in decoding:
                     tokens[i, 0] = self.slot_req[i].tokens[-1]
@@ -633,16 +650,21 @@ class ContinuousBatcher:
                 # (refill-mid-decode safety).  A copy, as for prefill:
                 # ``slot_pos`` moves on before the launch has run.
                 cache_index = jnp.asarray(self.slot_pos.copy(), jnp.int32)
-                logits, self.cache = self.decode_fn(
+                logits, self.cache, routing = self.decode_fn(
                     self.params, self.cache,
                     {"tokens": jnp.asarray(tokens),
                      "cache_index": cache_index})
+                self._keep_routing(args, routing)
             with obs.trace_span("serve", "token_fetch"):
                 # argmax over the REAL vocab: the padded tail rows of the
                 # lm_head are arbitrary init values, and generated ids
                 # must stay submittable (no silent % vocab anywhere)
-                nxt = np.asarray(
-                    jnp.argmax(logits[:, :self.cfg.vocab], axis=-1))
+                ids = jnp.argmax(logits[:, :self.cfg.vocab], axis=-1)
+                if self._routing:
+                    ids, counts = jax.device_get(
+                        (ids, [c for _, c in self._routing]))
+                    self._put_routing(counts)
+                nxt = np.asarray(ids)
         # ``held`` (requests in slots or queued at the end) is filled in
         # last, so the span's args carry the state the step left
         complete_args = {} if obs.enabled() else None
@@ -720,6 +742,18 @@ class ContinuousBatcher:
                     r is not None for r in self.slot_req)
         self.vtime += max(1, step_cost)
         self._post_step(now)
+
+    def _keep_routing(self, args: Optional[dict], routing: dict):
+        """Hold a launch's routing counts, while tracing, until the next
+        token fetch puts them in its span's ``args``."""
+        if args is not None and routing:
+            self._routing.append((args, routing))
+
+    def _put_routing(self, counts: list):
+        """Write the fetched counts into their launches' span args."""
+        for (args, _), c in zip(self._routing, counts):
+            args.update({k: int(v) for k, v in c.items()})
+        self._routing = []
 
     def _post_step(self, now: int):
         """Once per step: feed the always-on metrics plane and (when

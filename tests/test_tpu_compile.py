@@ -191,3 +191,51 @@ def test_phi3_step_programs_write_the_cache_in_place(one_chip):
         mem = compiled.memory_analysis()
         assert mem.alias_size_in_bytes >= cache_bytes, (
             mem.alias_size_in_bytes, cache_bytes)
+
+
+def test_moonlight_step_programs_write_the_latent_cache_in_place(one_chip):
+    """Moonlight-16B-A3B's decode and prefill programs at the benchmark
+    cell's shape (its ``n_slots`` x 8192 positions, 16 of 64 experts held,
+    chunks of 32), jitted as ``ContinuousBatcher`` jits them: the latent
+    cache is aliased to the output and no op copies, transposes or
+    writes back a whole layer of it.  Prints each program's temp bytes."""
+    import json
+    from pathlib import Path
+
+    from repro.configs import get_config
+    from repro.models import model as MDL
+    from repro.serve.batcher import step_programs
+
+    cfg = get_config("moonlight-16b-a3b")
+    cell = json.loads((Path(__file__).resolve().parents[1] / "chipbench"
+                       / "configs" / "moonlight-16b-a3b.json").read_text())
+    slots, T, chunk = cell["n_slots"], cell["cache_len"], 32
+    assert (T, cfg.experts_held, cfg.n_experts) == (8192, 16, 64)
+    lane = MDL.lane_width(next(iter(one_chip.device_set)))
+
+    def place(tree):
+        return jax.tree.map(lambda s: _shape(one_chip, s.shape, s.dtype),
+                            tree)
+
+    params = place(MDL.param_shapes(cfg))
+    cache = place(MDL.cache_shapes(cfg, slots, T, lane))
+    entry = cache["layers"]["latent"].shape[-1]
+    assert cache["layers"]["latent"].shape == (26, slots, 1, T, 640)
+    cache_bytes = sum(s.size * s.dtype.itemsize
+                      for s in jax.tree.leaves(cache))
+    ints = lambda *s: _shape(one_chip, s, jnp.int32)  # noqa: E731
+    decode_fn, prefill_fn = step_programs(cfg)
+    for role, fn, batch in (
+            ("decode", decode_fn, {"tokens": ints(slots, 1),
+                                   "cache_index": ints(slots)}),
+            ("prefill", prefill_fn, {"tokens": ints(slots, chunk),
+                                     "cache_index": ints(slots),
+                                     "count": ints(slots)})):
+        compiled = fn.lower(params, cache, batch).compile()
+        moves = _layer_sized_moves(compiled.as_text(), slots, T, entry)
+        assert not moves, (role, moves)
+        mem = compiled.memory_analysis()
+        assert mem.alias_size_in_bytes >= cache_bytes, (
+            role, mem.alias_size_in_bytes, cache_bytes)
+        print(f"moonlight {role}: temp {mem.temp_size_in_bytes} B, "
+              f"arguments {mem.argument_size_in_bytes} B")
